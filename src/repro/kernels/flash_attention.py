@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import default_interpret
+
 NEG_INF = -1e30
 
 
@@ -115,9 +117,14 @@ def flash_attention(
     logit_cap: float = 0.0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
-    """q: (B,Hq,Sq,d); k,v: (B,Hkv,Skv,d) → (B,Hq,Sq,d). GQA via Hq=G·Hkv."""
+    """q: (B,Hq,Sq,d); k,v: (B,Hkv,Skv,d) → (B,Hq,Sq,d). GQA via Hq=G·Hkv.
+
+    ``interpret=None`` interprets off the TPU only (:func:`default_interpret`).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     B, Hq, Sq, d = q.shape
     _, Hkv, Skv, dv = v.shape
     assert Hq % Hkv == 0, (Hq, Hkv)

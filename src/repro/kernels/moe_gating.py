@@ -32,46 +32,70 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from .backend import default_interpret
 
 
 def _gating_kernel(logits_ref, idx_ref, gate_ref, pos_ref,
                    *, top_k: int, capacity: int, renormalise: bool):
-    x = logits_ref[0].astype(jnp.float32)  # (N, E)
+    f32 = jnp.float32
+    x = logits_ref[0].astype(f32)  # (N, E)
     N, E = x.shape
     # softmax over experts
     m = x.max(axis=-1, keepdims=True)
     p = jnp.exp(x - m)
     probs = p / p.sum(axis=-1, keepdims=True)
 
-    counts = jnp.zeros((E,), jnp.int32)
+    # integer bookkeeping in f32 (exact far beyond any N, E) — Mosaic reduces
+    # floats across lanes, and has no cumsum: per-expert prefix counts over
+    # tokens are a lower-triangular matmul, exact with 0/1 bf16 operands and
+    # f32 accumulation
+    expert = jax.lax.broadcasted_iota(jnp.int32, (N, E), 1).astype(f32)
+    tri = (
+        jax.lax.broadcasted_iota(jnp.int32, (N, N), 1)
+        <= jax.lax.broadcasted_iota(jnp.int32, (N, N), 0)
+    ).astype(jnp.bfloat16)
+    pick = jax.lax.broadcasted_iota(jnp.int32, (N, top_k), 1)
+    idx = jnp.zeros((N, top_k), f32)
+    gate = jnp.zeros((N, top_k), f32)
+    pos = jnp.zeros((N, top_k), f32)
+    counts = jnp.zeros((1, E), f32)
     remaining = probs
-    gates = []
     for j in range(top_k):  # static k → unrolled
-        g_j = remaining.max(axis=-1)  # (N,)
-        e_j = jnp.argmax(remaining, axis=-1).astype(jnp.int32)  # first max wins
-        onehot = jax.nn.one_hot(e_j, E, dtype=jnp.int32)  # (N, E)
+        g_j = remaining.max(axis=-1, keepdims=True)  # (N, 1)
+        # first max wins (jax.lax.top_k's tie-breaking)
+        e_j = jnp.min(jnp.where(remaining == g_j, expert, float(E)),
+                      axis=-1, keepdims=True)
+        onehot = (expert == e_j).astype(f32)  # (N, E)
         # capacity slot: tokens earlier in the group claim lower slots
-        slot_grid = counts[None, :] + jnp.cumsum(onehot, axis=0) - onehot
-        slot = jnp.sum(slot_grid * onehot, axis=-1)  # (N,)
-        kept = slot < capacity
-        idx_ref[0, :, j] = e_j
-        pos_ref[0, :, j] = jnp.where(kept, slot, -1).astype(jnp.int32)
-        gates.append(g_j)
-        counts = counts + onehot.sum(axis=0)
+        earlier = jax.lax.dot_general(
+            tri, onehot.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=f32,
+        ) - onehot
+        slot = jnp.sum((counts + earlier) * onehot, axis=-1, keepdims=True)  # (N, 1)
+        here = pick == j
+        idx = jnp.where(here, e_j, idx)
+        gate = jnp.where(here, g_j, gate)
+        pos = jnp.where(here, jnp.where(slot < capacity, slot, -1.0), pos)
+        counts = counts + onehot.sum(axis=0, keepdims=True)
         remaining = jnp.where(onehot > 0, -jnp.inf, remaining)
-    gate = jnp.stack(gates, axis=-1)  # (N, k)
     if renormalise:
         gate = gate / jnp.maximum(gate.sum(axis=-1, keepdims=True), 1e-9)
+    idx_ref[0] = idx.astype(jnp.int32)
     gate_ref[0] = gate
+    pos_ref[0] = pos.astype(jnp.int32)
 
 
 @functools.partial(
     jax.jit, static_argnames=("top_k", "capacity", "renormalise", "interpret")
 )
 def moe_gating_pallas(logits, *, top_k: int, capacity: int,
-                      renormalise: bool = True, interpret: bool = True):
-    """logits: (G, N, E) → (idx (G,N,k) i32, gate (G,N,k) f32, pos (G,N,k) i32)."""
+                      renormalise: bool = True, interpret: bool | None = None):
+    """logits: (G, N, E) → (idx (G,N,k) i32, gate (G,N,k) f32, pos (G,N,k) i32).
+
+    ``interpret=None`` interprets off the TPU only (:func:`default_interpret`).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     G, N, E = logits.shape
     kernel = functools.partial(
         _gating_kernel, top_k=top_k, capacity=capacity, renormalise=renormalise

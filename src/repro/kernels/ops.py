@@ -2,9 +2,9 @@
 
 Each op has three execution paths, chosen per call site:
 
-* ``use_pallas=True`` → the Pallas kernel (Mosaic on TPU; ``interpret=True``
-  executes the same kernel body in Python on CPU — how this container
-  validates them);
+* ``use_pallas=True`` → the Pallas kernel (Mosaic on TPU; off the TPU the
+  same kernel body runs in the Pallas interpreter, see
+  :func:`repro.kernels.backend.default_interpret`);
 * ``use_pallas=False`` → the XLA path (chunked-flash attention /
   chunked WKV / associative scan) — identical math, compiler-scheduled;
 * gradients: the Pallas kernels are *forward* kernels wrapped in
@@ -31,14 +31,6 @@ from .rmsnorm import rmsnorm_pallas
 from .rwkv6_scan import wkv6_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _interpret() -> bool:
-    return not _on_tpu()
-
-
 # ---------------------------------------------------------------------------
 # Attention
 # ---------------------------------------------------------------------------
@@ -48,10 +40,7 @@ def _interpret() -> bool:
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
 )
 def _attention_pallas(q, k, v, causal, window, logit_cap, kv_chunk):
-    return flash_attention(
-        q, k, v, causal=causal, window=window, logit_cap=logit_cap,
-        interpret=_interpret(),
-    )
+    return flash_attention(q, k, v, causal=causal, window=window, logit_cap=logit_cap)
 
 
 def _attention_xla(q, k, v, causal, window, logit_cap, kv_chunk):
@@ -99,7 +88,7 @@ def attention(
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _wkv6_p(r, k, v, w, u, s0, chunk):
-    return wkv6_pallas(r, k, v, w, u, s0, chunk=chunk, interpret=_interpret())
+    return wkv6_pallas(r, k, v, w, u, s0, chunk=chunk)
 
 
 def _wkv6_xla(r, k, v, w, u, s0, chunk):
@@ -136,7 +125,7 @@ def wkv6(r, k, v, w, u, s0, *, chunk: int = 64, use_pallas: bool = False):
 
 @jax.custom_vjp
 def _lru_p(a, b, h0):
-    return lru_pallas(a, b, h0, interpret=_interpret())
+    return lru_pallas(a, b, h0)
 
 
 def _lru_xla(a, b, h0):
@@ -183,7 +172,7 @@ def lru_scan(a, b, h0, *, use_pallas: bool = False):
 
 @jax.custom_vjp
 def _rmsnorm_p(x, w):
-    return rmsnorm_pallas(x, w, interpret=_interpret())
+    return rmsnorm_pallas(x, w)
 
 
 def _rmsnorm_xla(x, w):

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import default_interpret
+
 
 def _lru_kernel(a_ref, b_ref, h0_ref, y_ref, hout_ref, h_ref,
                 *, chunk: int, n_chunks: int):
@@ -34,14 +36,13 @@ def _lru_kernel(a_ref, b_ref, h0_ref, y_ref, hout_ref, h_ref,
 
     @pl.when(ci == 0)
     def _init():
-        h_ref[...] = h0_ref[...].astype(jnp.float32)  # (1, bw)
-
-    a = a_ref[0].astype(jnp.float32)  # (C, bw)
-    b = b_ref[0].astype(jnp.float32)
+        h_ref[...] = h0_ref[0].astype(jnp.float32)  # (1, bw)
 
     def step(t, h):
-        h = a[t][None, :] * h + b[t][None, :]
-        y_ref[0, t] = h[0].astype(y_ref.dtype)
+        row = pl.ds(t, 1)
+        h = (a_ref[0, row, :].astype(jnp.float32) * h
+             + b_ref[0, row, :].astype(jnp.float32))  # (1, bw)
+        y_ref[0, row, :] = h.astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, chunk, step, h_ref[...])
@@ -49,13 +50,18 @@ def _lru_kernel(a_ref, b_ref, h0_ref, y_ref, hout_ref, h_ref,
 
     @pl.when(ci == n_chunks - 1)
     def _finish():
-        hout_ref[...] = h
+        hout_ref[0] = h
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_w", "interpret"))
 def lru_pallas(a, b, h0, *, chunk: int = 128, block_w: int = 512,
-               interpret: bool = True):
-    """a, b: (B, T, W); h0: (B, W). Returns (h_seq (B,T,W) in a.dtype, h_final f32)."""
+               interpret: bool | None = None):
+    """a, b: (B, T, W); h0: (B, W). Returns (h_seq (B,T,W) in a.dtype, h_final f32).
+
+    ``interpret=None`` interprets off the TPU only (:func:`default_interpret`).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     B, T, W = a.shape
     C = min(chunk, T)
     assert T % C == 0, (T, C)
@@ -71,20 +77,20 @@ def lru_pallas(a, b, h0, *, chunk: int = 128, block_w: int = 512,
         in_specs=[
             pl.BlockSpec((1, C, bw), lambda b_, w_, c: (b_, c, w_)),
             pl.BlockSpec((1, C, bw), lambda b_, w_, c: (b_, c, w_)),
-            pl.BlockSpec((1, bw), lambda b_, w_, c: (b_, w_)),
+            pl.BlockSpec((1, 1, bw), lambda b_, w_, c: (b_, 0, w_)),
         ],
         out_specs=[
             pl.BlockSpec((1, C, bw), lambda b_, w_, c: (b_, c, w_)),
-            pl.BlockSpec((1, bw), lambda b_, w_, c: (b_, w_)),
+            pl.BlockSpec((1, 1, bw), lambda b_, w_, c: (b_, 0, w_)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, T, W), a.dtype),
-            jax.ShapeDtypeStruct((B, W), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(a, b, h0)
-    return y, h_fin
+    )(a, b, h0.reshape(B, 1, W))
+    return y, h_fin.reshape(B, W)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import default_interpret
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)  # (rows, D)
@@ -32,8 +34,13 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm_pallas(x, weight, *, eps: float = 1e-6, block_rows: int = 128,
-                   interpret: bool = True):
-    """x: (..., D); weight: (D,). Fused row-wise RMSNorm."""
+                   interpret: bool | None = None):
+    """x: (..., D); weight: (D,). Fused row-wise RMSNorm.
+
+    ``interpret=None`` interprets off the TPU only (:func:`default_interpret`).
+    """
+    if interpret is None:
+        interpret = default_interpret()
     orig_shape = x.shape
     D = orig_shape[-1]
     xf = x.reshape(-1, D)

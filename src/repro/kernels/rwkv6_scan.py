@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .backend import default_interpret
+
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
                 y_ref, sout_ref, s_ref, *, chunk: int, n_chunks: int):
@@ -46,24 +48,28 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)  # (C, dv)
     w = w_ref[0, 0].astype(jnp.float32)  # (C, dk), in (0,1)
-    u = u_ref[0].astype(jnp.float32)  # (dk,)
+    u = u_ref[0].astype(jnp.float32)  # (1, dk)
     s = s_ref[...]  # (dk, dv)
 
+    C = chunk
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    tri = col < row  # s < t
+
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    lc = jnp.cumsum(logw, axis=0)  # inclusive (C, dk)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum)
+    lc = jax.lax.dot_general(
+        (col <= row).astype(jnp.float32), logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )  # (C, dk)
     lc_excl = lc - logw
 
     # in-chunk pairwise term: A[t,s] = Σ_i r[t,i] k[s,i] e^{lc_excl[t,i]-lc[s,i]}
     ratio = jnp.exp(lc_excl[:, None, :] - lc[None, :, :])  # (C, C, dk), ≤1 under tri
-    C = chunk
-    tri = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1) < jax.lax.broadcasted_iota(
-        jnp.int32, (C, C), 0
-    )  # s < t
-    A = jnp.einsum(
-        "ti,tsi,si->ts", r, ratio, k, preferred_element_type=jnp.float32
-    )
+    A = jnp.sum(r[:, None, :] * ratio * k[None, :, :], axis=-1)  # (C, C)
     A = jnp.where(tri, A, 0.0)
-    diag = jnp.sum(r * u[None, :] * k, axis=-1)  # (C,)
+    diag = jnp.sum(r * u * k, axis=-1)  # (C,)
     y = (
         jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -74,9 +80,14 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # state update: S' = e^{lc[-1]} ⊙ S + Σ_s (k_s e^{lc[-1]-lc[s]}) v_s^T
-    decay_all = jnp.exp(lc[-1])  # (dk,)
-    k_scaled = k * jnp.exp(lc[-1][None, :] - lc)  # (C, dk), ≤1
-    s_new = decay_all[:, None] * s + jax.lax.dot_general(
+    lc_last = lc[C - 1 : C]  # (1, dk)
+    # the same chunk total as a (dk, 1) column, to scale the state's rows
+    lc_last_col = jax.lax.dot_general(
+        logw, jnp.ones((C, 1), jnp.float32), (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
+    )
+    k_scaled = k * jnp.exp(lc_last - lc)  # (C, dk), ≤1
+    s_new = jnp.exp(lc_last_col) * s + jax.lax.dot_general(
         k_scaled, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     s_ref[...] = s_new
@@ -87,11 +98,15 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6_pallas(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
+def wkv6_pallas(r, k, v, w, u, s0, *, chunk: int = 64,
+                interpret: bool | None = None):
     """r,k,w: (B,H,T,dk); v: (B,H,T,dv); u: (H,dk); s0: (B,H,dk,dv) f32.
 
     Returns (y: (B,H,T,dv) in r.dtype, s_final: (B,H,dk,dv) f32).
+    ``interpret=None`` interprets off the TPU only (:func:`default_interpret`).
     """
+    if interpret is None:
+        interpret = default_interpret()
     B, H, T, dk = r.shape
     dv = v.shape[-1]
     C = min(chunk, T)
@@ -108,7 +123,7 @@ def wkv6_pallas(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
             pl.BlockSpec((1, 1, C, dk), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, dv), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, C, dk), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, dk), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b, h, c: (h, 0, 0)),
             pl.BlockSpec((1, 1, dk, dv), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -124,5 +139,5 @@ def wkv6_pallas(r, k, v, w, u, s0, *, chunk: int = 64, interpret: bool = True):
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(H, 1, dk), s0)
     return y, s_fin

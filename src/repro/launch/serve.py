@@ -26,10 +26,29 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 from repro.parallel.sharding import resolve_tree, rules_for
 from repro.training.steps import make_prefill_step, make_serve_step
+
+
+def _init_params(model, mesh, rules, seed: int):
+    """Random weights made on the device under their shardings, in one jitted
+    program: eager init would hold each leaf's float32 draw beside the
+    weights already made, which a 4B-parameter model cannot afford in 16 GB."""
+    shardings = resolve_tree(mesh, model.param_logical(), rules)
+    with mesh:
+        return jax.jit(model.init, out_shardings=shardings)(jax.random.PRNGKey(seed))
+
+
+def sample_tokens(logits, vocab_size: int, temperature: float = 0.0, rng=None):
+    """Greedy (``temperature <= 0``) or temperature sampling over the real
+    vocabulary: the padded tail of the embedding is never emitted."""
+    logits = logits[..., :vocab_size]
+    if temperature <= 0:
+        return jnp.argmax(logits, axis=-1)
+    return jax.random.categorical(rng, logits / temperature, axis=-1)
 
 
 def pad_cache_to(cache, cache_defs):
@@ -69,8 +88,7 @@ class ServeEngine:
             extra_dims={"kv_seq": max_seq, "heads": cfg.n_heads},
         )
         self.rules = rules
-        with self.mesh:
-            self.params = self.model.init(jax.random.PRNGKey(seed))
+        self.params = _init_params(self.model, self.mesh, rules, seed)
         self._prefill = jax.jit(make_prefill_step(self.model, rules, self.mesh))
         self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh))
         self.stats = {"requests": 0, "prefill_tokens": 0, "decode_tokens": 0,
@@ -102,7 +120,7 @@ class ServeEngine:
             out = np.zeros((B, gen_len), np.int32)
             finished = np.zeros((B,), bool)
             rng = rng or jax.random.PRNGKey(0)
-            tok = self._sample(logits[:, -1], temperature, rng)
+            tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, rng)
             for i in range(gen_len):
                 out[:, i] = np.where(finished, eos_id or 0, np.asarray(tok))
                 if eos_id is not None:
@@ -115,7 +133,7 @@ class ServeEngine:
                     self.params, cache, jnp.asarray(out[:, i : i + 1]), pos
                 )
                 rng, sub = jax.random.split(rng)
-                tok = self._sample(logits[:, -1], temperature, sub)
+                tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, sub)
             jax.block_until_ready(logits)
         t2 = time.perf_counter()
         self.stats["requests"] += B
@@ -124,12 +142,6 @@ class ServeEngine:
         self.stats["prefill_s"] += t1 - t0
         self.stats["decode_s"] += t2 - t1
         return out
-
-    @staticmethod
-    def _sample(logits, temperature, rng):
-        if temperature <= 0:
-            return jnp.argmax(logits, axis=-1)
-        return jax.random.categorical(rng, logits / temperature, axis=-1)
 
     # -- dynamic batcher ----------------------------------------------------------
 
@@ -187,8 +199,7 @@ class ContinuousBatchingEngine:
             cfg, self.mesh, param_defs=self.model.param_defs, batch_size=batch,
             extra_dims={"kv_seq": max_seq, "heads": cfg.n_heads},
         )
-        with self.mesh:
-            self.params = self.model.init(jax.random.PRNGKey(seed))
+        self.params = _init_params(self.model, self.mesh, rules, seed)
         self._prefill = jax.jit(make_prefill_step(self.model, rules, self.mesh))
         self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh))
         self.stats = {"requests": 0, "decode_steps": 0, "slot_tokens": 0,
@@ -205,7 +216,7 @@ class ContinuousBatchingEngine:
         cache = jax.tree_util.tree_map(
             lambda full, one: full.at[:, slot].set(one[:, 0]), cache, row_cache
         )
-        return cache, int(jnp.argmax(logits[0, -1]))
+        return cache, int(sample_tokens(logits[0, -1], self.cfg.vocab_size))
 
     def serve(self, requests: list, gen_len: int) -> list:
         """Greedy-decode every request; returns outputs in input order."""
@@ -245,7 +256,7 @@ class ContinuousBatchingEngine:
                     jnp.asarray(cur_tok[:, None], jnp.int32),
                     jnp.asarray(pos, jnp.int32),
                 )
-                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+                nxt = np.asarray(sample_tokens(logits[:, -1], self.cfg.vocab_size))
                 for b in range(B):
                     if slot_req[b] == -1:
                         continue
@@ -274,6 +285,7 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     engine = ServeEngine(

@@ -39,6 +39,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import get_config, get_smoke_config
 from repro.core.eco import EcoScheduler
 from repro.data import make_train_loader
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
 from repro.optim import make_optimizer
@@ -104,8 +105,10 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
     manager = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
     start_step = 0
     data_cursor = 0
+    # jitted so each weight is made where its sharding puts it
+    init = jax.jit(lambda rng: init_train_state(model, optimizer, rng), out_shardings=state_sh)
     with mesh:
-        state = init_train_state(model, optimizer, jax.random.PRNGKey(args.seed))
+        state = init(jax.random.PRNGKey(args.seed))
     if manager and manager.latest_step() is not None:
         state, extra, start_step = manager.restore(state, shardings=state_sh)
         data_cursor = int(extra.get("data_cursor", start_step))
@@ -204,6 +207,7 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
         "stopped": stop["reason"],
         "metrics": metrics_hist,
         "final_loss": metrics_hist[-1]["loss"] if metrics_hist else None,
+        "state": state,
     }
 
     if manager and (stop["reason"] or args.steps > start_step):
@@ -223,6 +227,7 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
 
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
+    enable_compile_cache()
     result = train(args)
     if result["final_loss"] is not None:
         print(f"[train] done: steps={result['completed_steps']} "

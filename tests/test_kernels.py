@@ -286,3 +286,43 @@ class TestMoEGating:
         p = np.asarray(pos)[0, :, 0]
         assert (p >= 0).sum() == 5
         assert np.array_equal(np.sort(p[p >= 0]), np.arange(5))
+
+
+class TestInterpretDefault:
+    """Each kernel interprets by default off the TPU and never on it."""
+
+    @staticmethod
+    def _kernels():
+        from repro.kernels.moe_gating import moe_gating_pallas
+
+        x = rand((1, 1, 8, 128))
+        return {
+            "flash_attention": lambda: flash_attention(x, x, x),
+            "rmsnorm": lambda: rmsnorm_pallas(rand((8, 128)), rand((128,))),
+            "lru": lambda: lru_pallas(rand((1, 8, 128)), rand((1, 8, 128)), rand((1, 128))),
+            "wkv6": lambda: wkv6_pallas(x, x, x, x, rand((1, 128)), rand((1, 1, 128, 128))),
+            "moe_gating": lambda: moe_gating_pallas(rand((1, 8, 4)), top_k=2, capacity=4),
+        }
+
+    @pytest.mark.parametrize("backend,interpret", [("tpu", False), ("cpu", True)])
+    @pytest.mark.parametrize(
+        "kernel", ["flash_attention", "rmsnorm", "lru", "wkv6", "moe_gating"]
+    )
+    def test_default_follows_backend(self, monkeypatch, kernel, backend, interpret):
+        from jax.experimental import pallas as pl
+
+        class Called(Exception):
+            pass
+
+        seen = []
+
+        def spy(*args, interpret=None, **kwargs):
+            seen.append(interpret)
+            raise Called
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        monkeypatch.setattr(pl, "pallas_call", spy)
+        jax.clear_caches()  # the kernels are jitted: trace them afresh
+        with pytest.raises(Called):
+            self._kernels()[kernel]()
+        assert seen == [interpret]

@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
-from repro.launch.serve import ServeEngine, pad_cache_to
+from repro.launch.serve import ServeEngine, pad_cache_to, sample_tokens
 from repro.models.registry import build_model
 
 
@@ -40,6 +40,16 @@ class TestPadCache:
         )
         with pytest.raises(ValueError, match="exceeds"):
             pad_cache_to(big, model.cache_defs_fn(1, 32))
+
+
+class TestSampling:
+    """Tokens come from the real vocabulary, never the embedding's padding."""
+
+    @pytest.mark.parametrize("temperature", [0.0, 1e-3])
+    def test_padded_vocab_never_sampled(self, temperature):
+        logits = jnp.zeros((3, 512)).at[:, 500:].set(50.0).at[:, 7].set(10.0)
+        tok = sample_tokens(logits, 500, temperature, jax.random.PRNGKey(0))
+        np.testing.assert_array_equal(np.asarray(tok), 7)
 
 
 class TestGeneration:
