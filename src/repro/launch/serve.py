@@ -29,6 +29,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
+from repro.obs.spans import span
 from repro.parallel.sharding import resolve_tree, rules_for
 from repro.training.steps import make_prefill_step, make_serve_step
 
@@ -107,34 +108,42 @@ class ServeEngine:
         cache_defs = self.model.cache_defs_fn(B, self.max_seq)
         t0 = time.perf_counter()
         with self.mesh:
-            batch_in = {"tokens": jnp.asarray(prompts, jnp.int32)}
-            if self.cfg.family == "encdec":
-                batch_in["frames"] = jnp.zeros(
-                    (B, self.cfg.enc_len, self.cfg.d_model), self.cfg.dtype
-                )
-            logits, cache = self._prefill(self.params, batch_in)
-            cache = pad_cache_to(cache, cache_defs)
-            jax.block_until_ready(logits)
+            with span("serve.prefill"):
+                batch_in = {"tokens": jnp.asarray(prompts, jnp.int32)}
+                if self.cfg.family == "encdec":
+                    batch_in["frames"] = jnp.zeros(
+                        (B, self.cfg.enc_len, self.cfg.d_model), self.cfg.dtype
+                    )
+                logits, cache = self._prefill(self.params, batch_in)
+                with span("serve.pad_cache"):
+                    cache = pad_cache_to(cache, cache_defs)
+                jax.block_until_ready(logits)
             t1 = time.perf_counter()
 
-            out = np.zeros((B, gen_len), np.int32)
-            finished = np.zeros((B,), bool)
-            rng = rng or jax.random.PRNGKey(0)
-            tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, rng)
-            for i in range(gen_len):
-                out[:, i] = np.where(finished, eos_id or 0, np.asarray(tok))
-                if eos_id is not None:
-                    finished |= out[:, i] == eos_id
-                    if finished.all():
-                        out = out[:, : i + 1]
-                        break
-                pos = jnp.asarray(P + i, jnp.int32)
-                logits, cache = self._decode(
-                    self.params, cache, jnp.asarray(out[:, i : i + 1]), pos
-                )
-                rng, sub = jax.random.split(rng)
-                tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, sub)
-            jax.block_until_ready(logits)
+            with span("serve.decode"):
+                out = np.zeros((B, gen_len), np.int32)
+                finished = np.zeros((B,), bool)
+                rng = rng or jax.random.PRNGKey(0)
+                with span("serve.sample"):
+                    tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, rng)
+                for i in range(gen_len):
+                    with span("serve.token_fetch"):
+                        tok = np.asarray(tok)
+                    out[:, i] = np.where(finished, eos_id or 0, tok)
+                    if eos_id is not None:
+                        finished |= out[:, i] == eos_id
+                        if finished.all():
+                            out = out[:, : i + 1]
+                            break
+                    with span("serve.decode_step"):
+                        pos = jnp.asarray(P + i, jnp.int32)
+                        logits, cache = self._decode(
+                            self.params, cache, jnp.asarray(out[:, i : i + 1]), pos
+                        )
+                    with span("serve.sample"):
+                        rng, sub = jax.random.split(rng)
+                        tok = sample_tokens(logits[:, -1], self.cfg.vocab_size, temperature, sub)
+                jax.block_until_ready(logits)
         t2 = time.perf_counter()
         self.stats["requests"] += B
         self.stats["prefill_tokens"] += B * P
@@ -159,15 +168,17 @@ class ServeEngine:
         """
         results: list = [None] * len(requests)
         buckets: dict[int, list[int]] = {}
-        for i, r in enumerate(requests):
-            buckets.setdefault(len(r), []).append(i)
+        with span("serve.batch"):
+            for i, r in enumerate(requests):
+                buckets.setdefault(len(r), []).append(i)
         for length, idxs in sorted(buckets.items()):
             for g in range(0, len(idxs), self.batch):
                 group = idxs[g : g + self.batch]
-                block = np.empty((self.batch, length), np.int32)
-                for row in range(self.batch):
-                    src = group[row] if row < len(group) else group[0]  # filler
-                    block[row] = requests[src]
+                with span("serve.batch"):
+                    block = np.empty((self.batch, length), np.int32)
+                    for row in range(self.batch):
+                        src = group[row] if row < len(group) else group[0]  # filler
+                        block[row] = requests[src]
                 out = self.generate_batch(block, gen_len, temperature=temperature)
                 for row, i in enumerate(group):
                     results[i] = out[row]
@@ -251,12 +262,16 @@ class ContinuousBatchingEngine:
                 active = np.array([s != -1 for s in slot_req])
                 self.stats["occupancy_sum"] += active.mean()
                 self.stats["decode_steps"] += 1
-                logits, cache = self._decode(
-                    self.params, cache,
-                    jnp.asarray(cur_tok[:, None], jnp.int32),
-                    jnp.asarray(pos, jnp.int32),
-                )
-                nxt = np.asarray(sample_tokens(logits[:, -1], self.cfg.vocab_size))
+                with span("serve.decode_step"):
+                    logits, cache = self._decode(
+                        self.params, cache,
+                        jnp.asarray(cur_tok[:, None], jnp.int32),
+                        jnp.asarray(pos, jnp.int32),
+                    )
+                with span("serve.sample"):
+                    nxt = sample_tokens(logits[:, -1], self.cfg.vocab_size)
+                with span("serve.token_fetch"):
+                    nxt = np.asarray(nxt)
                 for b in range(B):
                     if slot_req[b] == -1:
                         continue

@@ -42,6 +42,7 @@ from repro.data import make_train_loader
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.registry import build_model
+from repro.obs.spans import span
 from repro.optim import make_optimizer
 from repro.optim.schedules import cosine_warmup
 from repro.parallel.sharding import resolve_tree, rules_for
@@ -74,6 +75,16 @@ def build_argparser() -> argparse.ArgumentParser:
 
 
 def train(args, *, mesh=None, on_metrics=None) -> dict:
+    """Run the training loop of ``args`` (see :func:`build_argparser`).
+
+    Every ``log_every`` steps, and at the last, the step's metrics are
+    pulled to the host, printed and passed to ``on_metrics``, with ``step``
+    and ``tokens_per_s``: the tokens of the steps since the previous pull
+    over the time since that pull (the first pull's interval starts before
+    the first step, so it alone holds the compile). Returns the completed
+    steps, why the loop stopped, the pulled metrics, the final loss and the
+    train state.
+    """
     # multi-host: under a multi-task SLURM job, join the jax.distributed
     # cluster and derive this host's data shard; no-op in single-process runs
     from repro.launch.distributed import maybe_initialize
@@ -159,22 +170,26 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
 
     # ---- loop ---------------------------------------------------------------
     metrics_hist = []
-    t_start = time.perf_counter()
     tokens_per_step = args.global_batch * args.seq
     step = start_step
     steps_done = start_step  # steps whose update actually applied
+    pulled_t, pulled_step = time.perf_counter(), start_step  # the previous pull
     try:
         with mesh:
             for step in range(start_step, args.steps):
-                batch_np = next(loader)
-                batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
-                state, metrics = step_fn(state, batch)
+                with span("train.data"):
+                    batch_np = next(loader)
+                    batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                with span("train.step"):
+                    state, metrics = step_fn(state, batch)
                 steps_done = step + 1
                 if (step + 1) % args.log_every == 0 or step + 1 == args.steps:
-                    m = {k: float(v) for k, v in metrics.items()}
-                    dt = time.perf_counter() - t_start
-                    done = step + 1 - start_step
-                    m.update(step=step + 1, tokens_per_s=tokens_per_step * done / dt)
+                    with span("train.log_sync"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                    now = time.perf_counter()
+                    rate = tokens_per_step * (step + 1 - pulled_step) / (now - pulled_t)
+                    pulled_t, pulled_step = now, step + 1
+                    m.update(step=step + 1, tokens_per_s=rate)
                     metrics_hist.append(m)
                     if on_metrics:
                         on_metrics(m)
@@ -185,12 +200,13 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
                         flush=True,
                     )
                 if manager and (step + 1) % args.ckpt_every == 0:
-                    manager.save(
-                        step + 1, state,
-                        extra={"data_cursor": loader.state_dict()["cursor"],
-                               "arch": args.arch},
-                        blocking=False,
-                    )
+                    with span("train.checkpoint"):
+                        manager.save(
+                            step + 1, state,
+                            extra={"data_cursor": loader.state_dict()["cursor"],
+                                   "arch": args.arch},
+                            blocking=False,
+                        )
                 if stop["reason"]:
                     break
                 if eco_deadline and clock() >= eco_deadline:
@@ -211,12 +227,13 @@ def train(args, *, mesh=None, on_metrics=None) -> dict:
     }
 
     if manager and (stop["reason"] or args.steps > start_step):
-        manager.save(
-            completed, state,
-            extra={"data_cursor": loader.state_dict()["cursor"], "arch": args.arch,
-                   "stopped": stop["reason"]},
-            blocking=True,
-        )
+        with span("train.checkpoint"):
+            manager.save(
+                completed, state,
+                extra={"data_cursor": loader.state_dict()["cursor"], "arch": args.arch,
+                       "stopped": stop["reason"]},
+                blocking=True,
+            )
     if stop["reason"] == "eco-preempt" and sched is not None:
         remaining_s = 3600  # conservative: at least an hour of work left
         directive = sched.begin_directive(remaining_s, clock())

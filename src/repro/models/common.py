@@ -19,12 +19,35 @@ Design notes
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+# ---------------------------------------------------------------------------
+# Named scopes
+# ---------------------------------------------------------------------------
+
+
+def scoped(name: str):
+    """Run the decorated function under ``jax.named_scope(name)``: the name
+    goes into the ``op_name`` metadata of every op it traces, forward,
+    recomputed and backward alike, and changes no computation. The scope
+    catalogue is in :mod:`repro.obs.spans`."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+
+        return inner
+
+    return wrap
+
 
 # ---------------------------------------------------------------------------
 # Parameter definitions
@@ -350,6 +373,7 @@ def causal_mask(sq: int, skv: int, q_offset=0):
 # ---------------------------------------------------------------------------
 
 
+@scoped("mlp")
 def swiglu(x, wg, wi, wo, dtype):
     g = jnp.einsum("bsd,df->bsf", x, wg.astype(dtype))
     h = jnp.einsum("bsd,df->bsf", x, wi.astype(dtype))
@@ -358,6 +382,7 @@ def swiglu(x, wg, wi, wo, dtype):
     return jnp.einsum("bsf,fd->bsd", h, wo.astype(dtype))
 
 
+@scoped("mlp")
 def geglu(x, wg, wi, wo, dtype):
     g = jnp.einsum("bsd,df->bsf", x, wg.astype(dtype))
     h = jnp.einsum("bsd,df->bsf", x, wi.astype(dtype))
@@ -366,6 +391,7 @@ def geglu(x, wg, wi, wo, dtype):
     return jnp.einsum("bsf,fd->bsd", h, wo.astype(dtype))
 
 
+@scoped("loss")
 def cross_entropy(logits, labels, *, z_loss: float = 1e-4):
     """Token-level CE with optional z-loss; logits may be vocab-sharded.
 

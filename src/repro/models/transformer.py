@@ -28,6 +28,7 @@ from .common import (
     causal_mask,
     cross_entropy,
     rms_norm,
+    scoped,
     shard,
     swiglu,
 )
@@ -113,6 +114,7 @@ def dense_param_defs(cfg: ArchConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
+@scoped("attention")
 def gqa_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
     dt = jnp.dtype(cfg.dtype)
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"].astype(dt))
@@ -160,6 +162,7 @@ def gqa_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
     return y
 
 
+@scoped("attention")
 def mla_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
     """Training-path MLA: expand latent projections to per-head q/k/v."""
     dt = jnp.dtype(cfg.dtype)
@@ -236,12 +239,14 @@ def run_stack(blocks, x, cfg: ArchConfig, apply_block):
 # ---------------------------------------------------------------------------
 
 
+@scoped("embed")
 def embed_tokens(params, cfg: ArchConfig, tokens):
     dt = jnp.dtype(cfg.dtype)
     h = jnp.take(params["embed"].astype(dt), tokens, axis=0)
     return shard(h, "batch", "seq", None)
 
 
+@scoped("lm_head")
 def unembed(params, cfg: ArchConfig, h):
     dt = jnp.dtype(cfg.dtype)
     table = (
@@ -335,6 +340,7 @@ def cache_logical(cfg: ArchConfig) -> dict:
     }
 
 
+@scoped("kv_cache_write")
 def scatter_seq(buf, update, pos):
     """Write `update` (..., 1, d) into `buf` (..., S, d) at index `pos`.
 
@@ -372,6 +378,7 @@ def _pos_mask(pos, batch: int, skv: int):
     return jnp.arange(skv)[None, None, None, None, :] <= pos_b[:, None, None, None, None]
 
 
+@scoped("decode_attention")
 def gqa_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
     """One-token attention against the cache; ``pos`` scalar or (B,)."""
     dt = jnp.dtype(cfg.dtype)
@@ -396,6 +403,7 @@ def gqa_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
     return y, {"k": k, "v": v}
 
 
+@scoped("decode_attention")
 def mla_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
     """Absorbed-matmul MLA decode over the compressed (ckv, k_rope) cache.
 

@@ -9,6 +9,9 @@ Import surface:
   submodules explicitly. ``trace`` imports ``repro.core.events``, so
   pulling it in eagerly here would cycle with core modules that use
   the registry.
+* :mod:`repro.obs.spans` — ``nbi:`` profiler spans on the device path
+  (the serving engines, ``train()``); it imports JAX only when a span is
+  made, and is always on (a no-op outside a ``jax.profiler`` trace).
 
 Instrumentation is **off by default**: :func:`get_registry` returns a
 no-op :class:`NullRegistry` until :func:`enable` is called (or the

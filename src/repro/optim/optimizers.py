@@ -191,7 +191,8 @@ def make_optimizer(name: str, **kw) -> Optimizer:
 def _clip_by_global_norm(grads, max_norm: float):
     if not max_norm or max_norm <= 0:
         return grads
-    sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree_util.tree_leaves(grads))
-    norm = jnp.sqrt(sq)
-    scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-12))
-    return _tmap(lambda g: g * scale, grads)
+    with jax.named_scope("clip"):
+        sq = sum(jnp.sum(jnp.square(g)) for g in jax.tree_util.tree_leaves(grads))
+        norm = jnp.sqrt(sq)
+        scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-12))
+        return _tmap(lambda g: g * scale, grads)

@@ -73,7 +73,8 @@ def make_train_step(model: Model, optimizer: Optimizer, rules: dict, mesh):
             (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 params, batch
             )
-            grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
+            with jax.named_scope("grad_cast"):
+                grads = jax.tree_util.tree_map(lambda g: g.astype(jnp.float32), grads)
         else:
             micro = jax.tree_util.tree_map(
                 lambda x: x.reshape(mb, x.shape[0] // mb, *x.shape[1:]), batch
@@ -82,9 +83,10 @@ def make_train_step(model: Model, optimizer: Optimizer, rules: dict, mesh):
             def acc(carry, mbatch):
                 gsum, lsum = carry
                 (l, m), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mbatch)
-                gsum = jax.tree_util.tree_map(
-                    lambda a, b: a + b.astype(jnp.float32), gsum, g
-                )
+                with jax.named_scope("grad_cast"):
+                    gsum = jax.tree_util.tree_map(
+                        lambda a, b: a + b.astype(jnp.float32), gsum, g
+                    )
                 return (gsum, lsum + l), m
 
             gzero = jax.tree_util.tree_map(
@@ -95,7 +97,8 @@ def make_train_step(model: Model, optimizer: Optimizer, rules: dict, mesh):
             loss = lsum / mb
             metrics = jax.tree_util.tree_map(lambda x: x.mean(), ms)
 
-        new_params, new_opt = optimizer.update(grads, state["opt"], params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(grads, state["opt"], params)
         gnorm = jnp.sqrt(
             sum(jnp.sum(jnp.square(g)) for g in jax.tree_util.tree_leaves(grads))
         )
