@@ -12,8 +12,10 @@ Design notes
   the MaxText idiom — one model definition serves every mesh.
 * **Attention** ships two XLA paths: a chunked flash-style scan (online
   softmax over KV blocks; bounded memory for 32k prefill) and a single-shot
-  path for tiny query lengths (decode). The Pallas TPU kernel in
-  :mod:`repro.kernels` plugs in above these via ``repro.kernels.ops``.
+  path for tiny query lengths (decode). On a TPU, causal full-sequence
+  self-attention takes the fused Pallas kernel of
+  :mod:`repro.kernels.causal_attention` instead (:func:`attention_path`);
+  ``ArchConfig.use_pallas`` routes the other cases to ``repro.kernels.ops``.
 """
 
 from __future__ import annotations
@@ -26,6 +28,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs.metrics import get_registry
 
 # ---------------------------------------------------------------------------
 # Named scopes
@@ -334,6 +338,72 @@ def attention_chunked(
 
 def _is_traced(x) -> bool:
     return isinstance(x, jax.core.Tracer)
+
+
+# ---------------------------------------------------------------------------
+# Attention (fused Pallas path) and the dispatch between the two
+# ---------------------------------------------------------------------------
+
+
+def attention_platform() -> str:
+    """Where the model's arrays live: the active mesh's devices, else JAX's
+    default backend."""
+    if _ACTIVE_MESH is not None:
+        return _ACTIVE_MESH.devices.flat[0].platform
+    return jax.default_backend()
+
+
+def attention_path(
+    q, k, *, causal: bool = True, window: int = 0, logit_cap: float = 0.0,
+    q_offset=0, fallback: str = "chunked",
+) -> str:
+    """``"fused"`` where full-sequence attention runs in the fused causal
+    kernel (:mod:`repro.kernels.causal_attention`): on a TPU, causal
+    self-attention (Sq == Skv, no query offset) with no window and no logit
+    cap, S a multiple of 128. Else ``fallback``, the path the caller keeps.
+
+    Counts the decision in ``nbi_attention_path_total{path=}`` (once per
+    trace, not per step)."""
+    S = q.shape[2]
+    fused = (
+        attention_platform() == "tpu"
+        and causal
+        and S == k.shape[2]
+        and not _is_traced(q_offset)
+        and q_offset == 0
+        and window == 0
+        and logit_cap == 0
+        and S % 128 == 0
+    )
+    path = "fused" if fused else fallback
+    get_registry().counter(
+        "nbi_attention_path_total",
+        "full-sequence attention calls traced, by the path they take",
+        labels=("path",),
+    ).labels(path=path).inc()
+    return path
+
+
+def fused_causal_attention(q, k, v, kv_heads: str = "kv_heads"):
+    """:func:`repro.kernels.causal_attention.causal_flash_attention` on the
+    active mesh: per device under ``shard_map`` (batch over the data axes,
+    heads as the rules split them), since a Pallas call is not partitioned
+    by the compiler. ``kv_heads`` is the logical axis of k's and v's heads.
+    """
+    from repro.kernels.causal_attention import causal_flash_attention
+
+    if _ACTIVE_RULES is None or _ACTIVE_MESH is None or _ACTIVE_MESH.size == 1:
+        return causal_flash_attention(q, k, v)
+    q_spec = logical_to_spec(("batch", "heads", None, None))
+    kv_spec = logical_to_spec(("batch", kv_heads, None, None))
+    if kv_spec[1] != q_spec[1]:  # KV heads not split as the query heads are
+        G = q.shape[1] // k.shape[1]
+        k, v = jnp.repeat(k, G, axis=1), jnp.repeat(v, G, axis=1)
+        kv_spec = q_spec
+    return jax.shard_map(
+        causal_flash_attention, mesh=_ACTIVE_MESH,
+        in_specs=(q_spec, kv_spec, kv_spec), out_specs=q_spec, check_vma=False,
+    )(q, k, v)
 
 
 def attention_single_shot(q, k, v, *, mask=None, logit_cap: float = 0.0):

@@ -24,9 +24,11 @@ from .common import (
     ParamDef,
     apply_rope,
     attention_chunked,
+    attention_path,
     attention_single_shot,
     causal_mask,
     cross_entropy,
+    fused_causal_attention,
     rms_norm,
     scoped,
     shard,
@@ -126,14 +128,20 @@ def gqa_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
     k = shard(k, "batch", "kv_heads", None, None)
     v = shard(v, "batch", "kv_heads", None, None)
     k_compact, v_compact = k, v  # cache keeps the Hkv layout
-    if cfg.use_pallas:
+    window = cfg.window if cfg.attention == "local" else 0
+    path = attention_path(q, k, window=window, logit_cap=cfg.logit_cap,
+                          fallback="pallas" if cfg.use_pallas else "chunked")
+    if path == "fused":
+        # the fused kernel maps query heads to KV heads in its index maps
+        out = fused_causal_attention(q, k, v)
+    elif path == "pallas":
         # Pallas flash kernel handles GQA in its index map (no KV expansion).
         from repro.kernels import ops as kops
 
         out = kops.attention(
             q, k, v,
             causal=True,
-            window=cfg.window if cfg.attention == "local" else 0,
+            window=window,
             logit_cap=cfg.logit_cap,
             kv_chunk=cfg.attn_chunk,
             use_pallas=True,
@@ -152,7 +160,7 @@ def gqa_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
         out = attention_chunked(
             q, k, v,
             causal=True,
-            window=cfg.window if cfg.attention == "local" else 0,
+            window=window,
             kv_chunk=cfg.attn_chunk,
             logit_cap=cfg.logit_cap,
         )
@@ -180,7 +188,10 @@ def mla_attention(p, x, cfg: ArchConfig, positions, collect: bool = False):
     q = jnp.concatenate([q_nope, q_rope], -1)
     q = shard(q, "batch", "heads", None, None)
     k = shard(k, "batch", "heads", None, None)
-    out = attention_chunked(q, k, v, causal=True, kv_chunk=cfg.attn_chunk)
+    if attention_path(q, k) == "fused":
+        out = fused_causal_attention(q, k, v, kv_heads="heads")
+    else:
+        out = attention_chunked(q, k, v, causal=True, kv_chunk=cfg.attn_chunk)
     y = jnp.einsum("bhsv,hvd->bsd", out, p["wo"].astype(dt))
     if collect:
         # compressed MLA cache: the latent ckv + shared roped k_rope

@@ -10,12 +10,14 @@ pytest-xdist workers different test lists.
 
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.causal_attention import causal_flash_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.moe_gating import moe_gating_pallas
 from repro.kernels.rglru_scan import lru_pallas
@@ -51,6 +53,34 @@ def test_flash_attention_gqa(spec):
     q = spec((1, 32, 2048, 128), BF16)
     kv = spec((1, 8, 2048, 128), BF16)
     hlo = compile_for_chip(lambda q, k, v: flash_attention(q, k, v, interpret=False), q, kv, kv)
+    assert "tpu_custom_call" in hlo
+
+
+def test_causal_attention_mla_train_width(spec):
+    """MiniCPM3's MLA training attention: batch 2, 40 heads, 4096 tokens,
+    q/k head dim 96, v head dim 64, bf16; forward and the q/k/v gradient."""
+    qk, v = spec((2, 40, 4096, 96), BF16), spec((2, 40, 4096, 64), BF16)
+
+    def fwd(q, k, v):
+        return causal_flash_attention(q, k, v, interpret=False)
+
+    def grad(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(F32).sum(), (0, 1, 2))(q, k, v)
+
+    assert "tpu_custom_call" in compile_for_chip(fwd, qk, qk, v)
+    hlo = compile_for_chip(grad, qk, qk, v)
+    for kernel in ("causal_flash_fwd", "causal_flash_dq", "causal_flash_dkv"):
+        assert re.search(rf"%[\w.]*{kernel}[\w.]* = .*tpu_custom_call", hlo), kernel
+
+
+def test_causal_attention_mistral_prefill_width(spec):
+    """Mistral-7B prefill: batch 8, 32 query heads over 8 KV heads of 128,
+    4096 tokens, bf16, forward."""
+    hlo = compile_for_chip(
+        lambda q, k, v: causal_flash_attention(q, k, v, interpret=False),
+        spec((8, 32, 4096, 128), BF16), spec((8, 8, 4096, 128), BF16),
+        spec((8, 8, 4096, 128), BF16),
+    )
     assert "tpu_custom_call" in hlo
 
 
