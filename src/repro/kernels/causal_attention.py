@@ -12,8 +12,9 @@ trip through HBM, in the forward pass and in both backward kernels.
   (query block, key block) pairs, scalar-prefetched (:func:`_live_blocks`),
   so no grid step, DMA or compute is spent above the diagonal. Only the
   diagonal block is masked.
-* Several heads a grid step (up to :data:`MAX_HEADS` query heads): the
-  per-step cost of the pipeline is paid once for all of them.
+* Several heads a grid step (up to :data:`MAX_HEADS` query heads, in a
+  loop inside the kernel): the per-step cost of the pipeline is paid once
+  for all of them.
 * GQA in the index maps: query head ``h`` reads key/value head ``h // G``;
   a dkv step takes whole groups of query heads and sums each into its
   key/value block. No expanded K/V is written to HBM.
@@ -102,6 +103,17 @@ def _live_blocks(n: int, keys_outer: bool = False, chunks: int = 1):
     return tuple(jnp.asarray(np.array(col, np.int32)) for col in zip(*visits))
 
 
+def _for_each_head(n: int):
+    """Run the decorated ``body(h)`` for the step's heads ``0..n-1`` as a loop
+    in the kernel, not unrolled: the kernel, and its compile time, do not
+    grow with the heads a step takes."""
+
+    def run(body):
+        lax.fori_loop(0, n, lambda h, carry: (body(h), carry)[1], 0)
+
+    return run
+
+
 def _params():
     return pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
 
@@ -124,7 +136,8 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     def update(diagonal: bool):
-        for h in range(q_ref.shape[0]):
+        @_for_each_head(q_ref.shape[0])
+        def _(h):
             v = v_ref[h // groups]
             s = lax.dot_general(q_ref[h], k_ref[h // groups], NT,
                                 preferred_element_type=jnp.float32)
@@ -144,7 +157,8 @@ def _fwd_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
     @pl.when(j == i)  # the diagonal is a query block's last visit
     def _finish():
         update(True)
-        for h in range(q_ref.shape[0]):
+        @_for_each_head(q_ref.shape[0])
+        def _(h):
             l = l_sc[h]
             o_ref[h] = (acc_sc[h] / _lanes(l, dv)).astype(o_ref.dtype)
             lse_ref[h] = (m_sc[h] + jnp.log(l)).T[:1]  # a row: queries in lanes
@@ -212,7 +226,8 @@ def _dq_kernel(qi_ref, ki_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
     def update(diagonal: bool):
-        for h in range(q_ref.shape[0]):
+        @_for_each_head(q_ref.shape[0])
+        def _(h):
             k = k_ref[h // groups]
             s = lax.dot_general(q_ref[h], k, NT, preferred_element_type=jnp.float32)
             if diagonal:
@@ -266,7 +281,8 @@ def _dkv_kernel(qi_ref, ki_ref, ci_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, di
     i, j, c = qi_ref[step], ki_ref[step], ci_ref[step]
 
     def update(diagonal: bool):
-        for h in range(q_ref.shape[0]):
+        @_for_each_head(q_ref.shape[0])
+        def _(h):
             kv = h // groups
             q, do = q_ref[h], do_ref[h]
             # keys in rows, queries in lanes: the row statistics broadcast down
