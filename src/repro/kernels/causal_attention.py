@@ -387,6 +387,7 @@ def causal_flash_attention(q, k, v, *, interpret: bool | None = None):
     """
     if interpret is None:
         interpret = default_interpret()
-    assert q.shape[1] % k.shape[1] == 0, (q.shape, k.shape)
+    if q.shape[1] % k.shape[1]:
+        raise ValueError(f"{q.shape[1]} query heads do not group over {k.shape[1]} key heads")
     q = q * (q.shape[-1] ** -0.5)  # in q's dtype, as attention_chunked scales
     return _attention(q, k, v, interpret)
