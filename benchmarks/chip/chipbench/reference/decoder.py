@@ -5,7 +5,7 @@ configuration file lists (no MiniCPM embedding, residual or logit scales; plain
 rotate-half RoPE; the vocabulary padded to ``padded_vocab_size`` rows, which
 take part in the loss's softmax). It imports nothing of the program.
 
-Every matrix product goes through :func:`mm`. At ``numerics="f32"`` the
+Every matrix product goes through ``common.mm``. At ``numerics="f32"`` the
 operands are float32 at ``Precision.HIGHEST``; at ``numerics="fp8"`` each
 operand is first rounded to float8 e4m3 with one scale per tensor, the
 control that a lower-precision path would give.
@@ -25,34 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HIGHEST = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0
-
-
-def round_e4m3(x):
-    """Round float32 to the nearest float8 e4m3 value (3 mantissa bits,
-    smallest normal 2^-6, subnormal step 2^-9, largest 448), in float32
-    arithmetic so that it runs where the fp8 type does not."""
-    _, e = jnp.frexp(x)  # x = m * 2^e with 0.5 <= |m| < 1
-    step = jnp.exp2(jnp.maximum(e - 1, -6) - 3).astype(jnp.float32)
-    return jnp.clip(jnp.round(x / step) * step, -F8_MAX, F8_MAX)
-
-
-def _to_fp8(x):
-    """x rounded to fp8 with one scale per tensor. The gradient passes
-    straight through the rounding, so the backward matmuls see the rounded
-    operands; rounding's own derivative, zero almost everywhere, would cut
-    the gradient instead of coarsening it."""
-    scale = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX
-    return x + jax.lax.stop_gradient(round_e4m3(x / scale) * scale - x)
-
-
-def mm(eq: str, a, b, numerics: str = "f32"):
-    a = a.astype(jnp.float32)
-    b = b.astype(jnp.float32)
-    if numerics == "fp8":
-        a, b = _to_fp8(a), _to_fp8(b)
-    return jnp.einsum(eq, a, b, precision=HIGHEST)
+# The model-independent pieces; ``round_e4m3``, ``lr_at`` and ``adamw_step``
+# are re-exported, part of this module's interface (``reference/__init__.py``).
+from .common import (_draw, adamw_step, get_path, leaf_keys, lr_at, mm,  # noqa: F401
+                     path_keys, round_e4m3, set_path, spec_paths)
 
 
 # ---------------------------------------------------------------------------
@@ -121,26 +97,9 @@ def weight_specs(cfg: dict) -> dict:
     }
 
 
-def _is_spec(x) -> bool:
-    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
-
-
 def leaf_paths(cfg: dict) -> list:
     """(path, shape, fan_in) in the order the seeded keys are handed out."""
-    flat, _ = jax.tree_util.tree_flatten_with_path(weight_specs(cfg), is_leaf=_is_spec)
-    return [(jax.tree_util.keystr(p), spec[0], spec[1]) for p, spec in flat]
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _draw(key, shape, fan_in, dtype):
-    if fan_in is None:
-        return jnp.ones(shape, dtype)
-    std = 1.0 / math.sqrt(max(1, fan_in))
-    return (jax.random.normal(key, shape) * std).astype(dtype)
-
-
-def leaf_keys(seed: int, n: int):
-    return jax.random.split(jax.random.PRNGKey(seed), n)
+    return spec_paths(weight_specs(cfg))
 
 
 def draw_leaf(cfg: dict, seed: int, index: int):
@@ -162,27 +121,11 @@ def init_weights(cfg: dict, seed: int) -> dict:
         keys = path_keys(path)
         if keys[0] == "blocks":
             for i in range(L):
-                _set(out["layers"][i], keys[1:], w[i])
+                set_path(out["layers"][i], keys[1:], w[i])
         else:
             out[keys[0]] = w
         del w
     return out
-
-
-def path_keys(path: str) -> list:
-    return [k.strip("'\"") for k in path.strip("[]").split("][")]
-
-
-def _set(tree: dict, keys: list, value) -> None:
-    for k in keys[:-1]:
-        tree = tree.setdefault(k, {})
-    tree[keys[-1]] = value
-
-
-def get_path(tree: dict, keys: list):
-    for k in keys:
-        tree = tree[k]
-    return tree
 
 
 def leaf_norms(tree: dict, cfg: dict) -> dict:
@@ -195,6 +138,24 @@ def leaf_norms(tree: dict, cfg: dict) -> dict:
                      for lay in tree["layers"])
         else:
             sq = float(jnp.sum(jnp.square(tree[keys[0]].astype(jnp.float32))))
+        out[path] = math.sqrt(sq)
+    return out
+
+
+def change_norms(params: dict, cfg: dict, seed: int) -> dict:
+    """Per program leaf (stacked over layers), the L2 norm of a per-layer
+    tree's change from the leaf's seeded start."""
+    out = {}
+    for index, (path, _, _) in enumerate(leaf_paths(cfg)):
+        start = draw_leaf(cfg, seed, index)
+        keys = path_keys(path)
+        if keys[0] == "blocks":
+            sq = sum(float(jnp.sum(jnp.square(get_path(lay, keys[1:]).astype(jnp.float32)
+                                              - start[i].astype(jnp.float32))))
+                     for i, lay in enumerate(params["layers"]))
+        else:
+            sq = float(jnp.sum(jnp.square(params[keys[0]].astype(jnp.float32)
+                                          - start.astype(jnp.float32))))
         out[path] = math.sqrt(sq)
     return out
 
@@ -392,42 +353,3 @@ def loss_and_grads(params, tokens, labels, cfg: dict, numerics: str = "f32",
     grads = {"layers": layer_grads, "embed": g_embed,
              "final_ln": acc["final_ln"] + g_ln, "unembed": acc["unembed"] + g_un}
     return loss, grads
-
-
-def lr_at(count: int, traffic: dict) -> float:
-    """Linear warm-up then cosine decay to 0, at optimizer step ``count``."""
-    peak, warm, total = traffic["lr"], traffic["warmup"], traffic["steps"]
-    if count < warm:
-        return peak * min(1.0, count / max(1, warm))
-    frac = min(max((count - warm) / max(1, total - warm), 0.0), 1.0)
-    return peak * 0.5 * (1 + math.cos(math.pi * frac))
-
-
-@functools.partial(jax.jit, static_argnums=(6,), donate_argnums=(0, 1, 2))
-def _adamw_leaf(p, m, v, g, lr, count, hyper):
-    b1, b2, eps, wd = hyper
-    m = b1 * m + (1 - b1) * g
-    v = b2 * v + (1 - b2) * g * g
-    c1 = 1 - b1 ** count
-    c2 = 1 - b2 ** count
-    p32 = p.astype(jnp.float32)
-    upd = (m / c1) / (jnp.sqrt(v / c2) + eps) + wd * p32
-    return (p32 - lr * upd).astype(p.dtype), m, v
-
-
-def adamw_step(params, m, v, grads, count: int, traffic: dict):
-    """AdamW with global-norm clipping, leaf by leaf. Returns (params, m, v,
-    clip scale): the optimizer got ``grads`` times that scale."""
-    o = traffic["optimizer"]
-    gl = jax.tree_util.tree_leaves(grads)
-    norm = math.sqrt(sum(float(jnp.sum(g * g)) for g in gl))
-    scale = min(1.0, o["clip_norm"] / max(norm, 1e-12)) if o["clip_norm"] else 1.0
-    lr = lr_at(count, traffic)
-    hyper = (o["b1"], o["b2"], o["eps"], o["weight_decay"])
-    pl, treedef = jax.tree_util.tree_flatten(params)
-    ml, vl = treedef.flatten_up_to(m), treedef.flatten_up_to(v)
-    new = [_adamw_leaf(p, m_, v_, g * scale, lr, float(count), hyper)
-           for p, m_, v_, g in zip(pl, ml, vl, gl)]
-    unf = functools.partial(jax.tree_util.tree_unflatten, treedef)
-    return (unf([n[0] for n in new]), unf([n[1] for n in new]), unf([n[2] for n in new]),
-            scale)

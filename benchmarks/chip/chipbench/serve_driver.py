@@ -20,7 +20,7 @@ import time
 import numpy as np
 
 from . import flops
-from .reference import decoder as ref
+from .reference import load as load_reference
 from .spec import arch_config
 from .traffic import serve_requests
 
@@ -155,9 +155,10 @@ def gaps(cfg: dict, served: dict, ids: list, seed: int, *, control: bool = False
     """The widest gap by which a served token's reference logit lies below
     the reference's best, over the sampled requests. With ``control`` the
     token compared at each position is the one the fp8 reference puts first
-    instead of the served one."""
+    instead of the served one. The reference is the configuration's."""
     import jax.numpy as jnp
 
+    ref = load_reference(cfg)
     params = ref.init_weights(cfg, seed)
     V = cfg["vocab_size"]
     worst, worst_ctl, tokens = 0.0, 0.0, 0

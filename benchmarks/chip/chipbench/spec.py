@@ -3,8 +3,10 @@
 A cell (an entry of ``workloads``) names a configuration and a traffic mix.
 The configuration's file is the one its ``configs`` entry gives; the traffic
 mix is ``traffic/<traffic>.json``; each per-layer metric is read by
-``metrics/<metric>.py``. Adding a configuration, a traffic mix or a metric is
-adding its file and its entry: nothing here changes.
+``metrics/<metric>.py``; the plain reference is the module under
+``reference/`` that the configuration file's ``reference`` key names. Adding a
+configuration, a traffic mix, a metric or a reference is adding its file and
+its entry: nothing here changes.
 """
 
 from __future__ import annotations
@@ -13,6 +15,8 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+
+from . import reference
 
 BENCH_DIR = Path(__file__).resolve().parents[1]
 ROOT = BENCH_DIR.parents[1]
@@ -35,6 +39,11 @@ _HF_KEYS = {
     "v_head_dim": "v_head_dim",
     "tie_word_embeddings": "tie_embeddings",
     "attn_chunk": "attn_chunk",
+    "n_routed_experts": "n_experts",
+    "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "moe_d_ff",
+    "n_shared_experts": "n_shared_experts",
+    "first_k_dense_replace": "n_dense_layers",
 }
 
 
@@ -67,7 +76,9 @@ def _reports(metric: dict, cell: str) -> bool:
 
 
 def resolve(name: str, *, spec: dict | None = None, smoke: bool = False) -> Cell:
-    """The cell ``name`` with its configuration, traffic and metrics."""
+    """The cell ``name`` with its configuration, traffic and metrics. Raises
+    ``reference.MissingReference`` where the configuration's reference
+    module is missing."""
     spec = spec or load_spec()
     cells = {w["name"]: w for w in spec["workloads"]}
     if name not in cells:
@@ -76,6 +87,7 @@ def resolve(name: str, *, spec: dict | None = None, smoke: bool = False) -> Cell
     configs = {c["name"]: c for c in spec["configs"]}
     cfg_file = ROOT / configs[w["config"]]["file"]
     config = _with_smoke(json.loads(cfg_file.read_text()), smoke)
+    reference.load(config)
     traffic = _with_smoke(traffic_file(w["traffic"]), smoke)
     return Cell(
         name=name,
@@ -101,11 +113,12 @@ def metric_reader(name: str):
 
 
 def arch_config(config: dict):
-    """The program's ``ArchConfig`` for a configuration file."""
+    """The program's ``ArchConfig`` for a configuration file. A key whose
+    value is null (``head_dim``, a ``q_lora_rank`` with no query compression)
+    is left out, so the field's default holds."""
     from repro.models.config import ArchConfig
 
-    fields = {field: config[key] for key, field in _HF_KEYS.items() if key in config}
+    fields = {field: config[key] for key, field in _HF_KEYS.items()
+              if config.get(key) is not None}
     fields.update(config.get("program", {}))
-    if not fields.get("head_dim"):
-        fields.pop("head_dim", None)
     return ArchConfig(name=config["name"], **fields)

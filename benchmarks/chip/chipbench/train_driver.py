@@ -28,7 +28,7 @@ from unittest import mock
 import numpy as np
 
 from . import flops
-from .reference import decoder as ref
+from .reference import load as load_reference
 from .spec import arch_config
 from .traffic import train_rows
 
@@ -76,6 +76,7 @@ def _change_norms(params, cfg: dict, seed: int) -> dict:
     import jax
     import jax.numpy as jnp
 
+    ref = load_reference(cfg)
     flat, _ = jax.tree_util.tree_flatten_with_path(params)
     leaves = {jax.tree_util.keystr(p): x for p, x in flat}
     diff = jax.jit(lambda a, b: jnp.sqrt(jnp.sum(jnp.square(
@@ -203,12 +204,14 @@ def reference_readings(cfg: dict, traffic: dict, seed: int, *, numerics: str = "
                        rows: slice = slice(None), chips: int = 1) -> dict:
     """The same readings from the plain reference: the losses of the checked
     steps, the first step's clipped gradient norms and the weights' change,
-    per leaf. ``rows`` keeps a part of each batch (a planted fault). The
-    reference takes each batch in ``chips`` blocks of rows, one chip's share
-    of it in the program, so that it fits on one chip."""
+    per leaf, from the configuration's reference module. ``rows`` keeps a
+    part of each batch (a planted fault). The reference takes each batch in
+    ``chips`` blocks of rows, one chip's share of it in the program, so that
+    it fits on one chip."""
     import jax
     import jax.numpy as jnp
 
+    ref = load_reference(cfg)
     steps = int(traffic["checked_steps"])
     params = ref.init_weights(cfg, seed)
     zeros = lambda tree: jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, jnp.float32), tree)
@@ -233,18 +236,7 @@ def reference_readings(cfg: dict, traffic: dict, seed: int, *, numerics: str = "
             grad_norms = {p: n * scale for p, n in raw.items()}
         del grads
     del m, v
-    change = {}
-    for index, (path, _, _) in enumerate(ref.leaf_paths(cfg)):
-        start = ref.draw_leaf(cfg, seed, index)
-        keys = ref.path_keys(path)
-        if keys[0] == "blocks":
-            sq = sum(float(jnp.sum(jnp.square(ref.get_path(lay, keys[1:]).astype(jnp.float32)
-                                              - start[i].astype(jnp.float32))))
-                     for i, lay in enumerate(params["layers"]))
-        else:
-            sq = float(jnp.sum(jnp.square(params[keys[0]].astype(jnp.float32)
-                                          - start.astype(jnp.float32))))
-        change[path] = math.sqrt(sq)
+    change = ref.change_norms(params, cfg, seed)
     return {"losses": losses, "grad_norms": grad_norms, "change_norms": change}
 
 

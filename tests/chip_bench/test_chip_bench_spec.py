@@ -229,3 +229,66 @@ def test_mla_counts_by_hand():
     assert flops.layer_norm_params(TINY_MLA) == 2 * 8 + 3 + 2
     assert math.isclose(flops.train_flops_per_token(TINY_MLA, 1),
                         6 * (204 + 80) + 3 * 1 * 2 * 5 * 2)
+
+
+# A tiny DeepSeek-V2-style block: MLA with no query compression, one leading
+# dense layer, then expert layers with 2 shared experts and 4 of 8 routed
+# experts held here, 2 a token.
+TINY_MOE = {"hidden_size": 8, "num_hidden_layers": 3, "num_attention_heads": 2,
+            "num_key_value_heads": 2, "intermediate_size": 16, "vocab_size": 10,
+            "q_lora_rank": None, "kv_lora_rank": 2, "qk_nope_head_dim": 2,
+            "qk_rope_head_dim": 2, "v_head_dim": 1, "first_k_dense_replace": 1,
+            "n_routed_experts": 4, "num_experts_per_tok": 2, "moe_intermediate_size": 3,
+            "n_shared_experts": 2, "published": {"n_routed_experts": 8},
+            "program": {"family": "moe", "attention": "mla", "dtype": "bfloat16",
+                        "param_dtype": "bfloat16"}}
+
+
+def test_mla_without_query_compression_counts_by_hand():
+    # wq 8*2*(2+2)=64 straight from the hidden state, wdkv 8*2, wukv 2*2*3,
+    # wkr 8*2, wo 2*1*8; no wdq, no query norm
+    assert flops.attn_matmul_params(TINY_MOE) == 64 + 16 + 12 + 16 + 16
+    assert flops.layer_matmul_params(TINY_MOE) == 124 + 3 * 8 * 16
+    assert flops.layer_norm_params(TINY_MOE) == 2 * 8 + 2
+
+
+def test_moe_operation_counts_by_hand():
+    # dense layer 124 + 384 = 508; an expert layer per token: attention 124,
+    # router 8*8 = 64 over all 8 experts, shared 2*(3*8*3) = 144, routed
+    # 2 * 4/8 = 1 expert of 72 on average: 404
+    per_token = 508 + 2 * 404
+    assert flops.token_matmul_params(TINY_MOE) == per_token
+    head = 80
+    # attention as for dense: H 2, qk 4, v 1 in each of the 3 layers
+    assert flops.train_flops_per_token(TINY_MOE, 4) == 6 * (per_token + head) + 3 * 3 * 2 * 5 * 5
+    assert flops.prefill_flops(TINY_MOE, 3) == 2 * per_token * 3 + 3 * 2 * 5 * 12 + 2 * head
+    assert flops.decode_flops(TINY_MOE, 3) == 2 * (per_token + head) + 2 * 3 * 2 * 5 * 4
+
+
+def test_moe_weight_bytes_by_hand():
+    # bf16. Dense layer 508 + norms 18; an expert layer 124 + 64 + 144 + 18
+    # = 350 and the held experts reached: one row reaches 4*(1 - 6/8) = 1 of
+    # 72, two rows 4*(1 - (6/8)^2) = 1.75, every held one 4; head 80, final norm 8
+    assert flops.weight_bytes(TINY_MOE, 1) == 2 * (526 + 2 * (350 + 72) + 80 + 8)
+    assert flops.weight_bytes(TINY_MOE, 2) == 2 * (526 + 2 * (350 + 1.75 * 72) + 88)
+    assert flops.weight_bytes(TINY_MOE) == 2 * (526 + 2 * (350 + 4 * 72) + 88)
+    # cache: kv_lora 2 + rope 2, bf16, in 3 layers, 5 positions of 2 rows
+    assert flops.decode_step_bytes(TINY_MOE, 2, 5.0) == 2 * (526 + 2 * (350 + 126) + 88) + 2 * 3 * 5 * 8
+
+
+def test_moe_without_a_published_count_holds_the_whole_layer():
+    whole = {k: v for k, v in TINY_MOE.items() if k != "published"}
+    # router 8*4 = 32; routed 2 * 4/4 = 2 experts of 72 a token
+    assert flops.token_matmul_params(whole) == 508 + 2 * (124 + 32 + 144 + 144)
+    # every row reaches 4 * (1 - (1 - 2/4)^1) = 2 held experts
+    assert flops.weight_bytes(whole, 1) == 2 * (526 + 2 * (124 + 32 + 144 + 18 + 144) + 88)
+
+
+def test_arch_config_maps_the_expert_keys_and_drops_nulls():
+    arch = spec.arch_config({**TINY_MOE, "name": "tiny-moe", "head_dim": None,
+                             "rope_theta": 10000.0})
+    assert (arch.family, arch.attention) == ("moe", "mla")
+    assert (arch.n_experts, arch.top_k, arch.moe_d_ff) == (4, 2, 3)
+    assert (arch.n_shared_experts, arch.n_dense_layers) == (2, 1)
+    assert arch.q_lora_rank == 0 and arch.head_dim == 0
+    assert (arch.kv_lora_rank, arch.qk_nope_dim, arch.qk_rope_dim, arch.v_head_dim) == (2, 2, 2, 1)
