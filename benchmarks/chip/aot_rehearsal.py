@@ -7,7 +7,9 @@ program's own jitted train step, or its prefill (one per prompt length) and
 decode step, at the cell's shapes on ``chips`` devices of a ``v5e:2x2``
 topology, compiles them with the TPU compiler and prints each program's
 ``memory_analysis()``. A program that does not fit in a chip's memory, or
-that the compiler refuses, fails here instead of on the chip.
+that the compiler refuses, fails here instead of on the chip. The Pallas
+kernels are lowered for Mosaic, as on the chip, and not for the interpreter
+that ``default_interpret`` would pick on this CPU.
 """
 
 from __future__ import annotations
@@ -122,8 +124,10 @@ def rehearse_serve(cell, devices) -> list:
 def main(argv: list[str]) -> int:
     from jax.experimental import topologies
 
+    import repro.kernels.causal_attention as causal_attention
     from chipbench.spec import load_spec, resolve
 
+    causal_attention.default_interpret = lambda: False
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     spec = load_spec()
     names = argv or [w["name"] for w in spec["workloads"]]

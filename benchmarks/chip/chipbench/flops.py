@@ -17,6 +17,9 @@ MLPs on average. A decode step of ``rows`` tokens reads the held experts
 they are expected to reach under uniform routing. MLA with a null
 ``q_lora_rank`` projects its query straight from the hidden state and has
 no query norm.
+
+A kernel's call is counted apart (:func:`causal_kernel_work`): the work of
+one call at its shapes, whatever blocks it computes in.
 """
 
 from __future__ import annotations
@@ -162,3 +165,34 @@ def decode_step_bytes(cfg: dict, rows: int, filled: float) -> float:
     """Weights plus ``filled`` cache positions of each of ``rows`` rows."""
     L = cfg["num_hidden_layers"]
     return float(weight_bytes(cfg, rows) + rows * L * filled * kv_bytes_per_position(cfg))
+
+
+# Multiply-adds per causal score of one call of each fused causal attention
+# kernel, as (times the query-key width, times the value width): the forward
+# pass forms q.k and p.v; dq recomputes q.k, forms do.v and ds.k; dkv
+# recomputes q.k, forms do.v, p.do and ds.q.
+CAUSAL_KERNELS = {"causal_flash_fwd": (1, 1), "causal_flash_dq": (2, 1),
+                  "causal_flash_dkv": (2, 2)}
+
+
+def causal_kernel_work(cfg: dict, rows: int, seq: int) -> dict:
+    """{kernel: [operations, bytes]} of one call of each kernel of
+    :data:`CAUSAL_KERNELS` over one layer's attention of ``rows`` rows of
+    ``seq`` positions. Operations: 2 per multiply-add, over the S(S+1)/2
+    scores at or before each query of every head and row; the kernels
+    compute whole blocks on the diagonal, so this is never more than a call
+    does. Bytes: the least a call moves, each operand read and each result
+    written once (MLA's key and value have a head per query head; the
+    softmax statistics are float32)."""
+    H, dqk, dv = attn_dims(cfg)
+    K = H if _mla(cfg) else cfg["num_key_value_heads"]
+    b = _BYTES[cfg.get("program", {}).get("dtype", "bfloat16")]
+    scores = rows * H * seq * (seq + 1) // 2
+    pos = rows * seq
+    q, o, k, v, stat = (pos * H * dqk * b, pos * H * dv * b, pos * K * dqk * b,
+                        pos * K * dv * b, pos * H * 4)
+    moved = {"causal_flash_fwd": q + k + v + o + stat,  # o and the log-sum-exp out
+             "causal_flash_dq": q + k + v + o + 2 * stat + q,  # do, lse, di in; dq out
+             "causal_flash_dkv": q + k + v + o + 2 * stat + k + v}  # dk, dv out
+    return {name: [float(2 * scores * (a * dqk + c * dv)), float(moved[name])]
+            for name, (a, c) in CAUSAL_KERNELS.items()}

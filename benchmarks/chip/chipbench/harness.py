@@ -9,11 +9,32 @@ trace of the window. Every number the check compares is printed beside its
 limit, as the last lines on standard error and under ``checks``, the last
 key of the result. Without a TPU, or with fewer chips than the cell asks
 for, the run exits non-zero and prints no result.
+
+A traced run also turns the program's counter registry on
+(``repro.obs.metrics``) before set-up and notes the jitted programs the
+window runs as set-up calls them (``programs.py``). After the window each
+per-layer metric's reader (``metrics/<name>.py``) gets one dict of values:
+the driver's, and
+
+- ``trace``: ``trace_reduce.reduce`` over the ``bench:`` spans;
+- ``spans``: ``span_reduce.reduce`` over every span and the compiled
+  programs: the program's ``nbi:`` spans (``span_stats``, ``idle_inside``),
+  device time by scope (``scope_incl_s[name]``, for any name on an op's
+  ``op_name`` path), by operation (``op_self_s[name]``, ``op_calls[name]``;
+  a Pallas kernel under its kernel's name), and runs and device time by
+  program (``program_runs``, ``program_s``);
+- ``counters``: every sample of the registry, ``{name: {"label=value,...":
+  value}}``;
+- ``peaks``: the chip's row of ``peaks.py``.
+
+So a new scope, kernel or counter of the program is read by a new metric
+file and entry. An untraced run turns nothing on and notes no program.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
 import gc
 import json
 import math
@@ -21,9 +42,10 @@ import shutil
 import sys
 import tempfile
 import time
+from pathlib import Path
 
 from . import peaks as peaks_mod
-from . import serve_driver, train_driver, trace_reduce
+from . import serve_driver, span_reduce, train_driver, trace_reduce
 from .spec import BENCH_DIR, metric_reader, resolve
 
 EXIT_NO_CHIP = 3
@@ -91,14 +113,52 @@ class Tracer:
         self._span = None
         jax.profiler.stop_trace()
 
-    def reduce(self) -> dict:
+    def reduce(self, hlo: list = (), keep: str = "") -> tuple:
+        """(``trace_reduce.reduce`` over the ``bench:`` spans alone,
+        ``span_reduce.reduce`` over every span), the trace loaded once;
+        ``hlo`` is [(label, compiled HLO text)] of the programs it ran. With
+        ``keep`` the trace is copied into that directory."""
         import glob
 
         files = glob.glob(f"{self.dir}/**/*.xplane.pb", recursive=True)
         try:
-            return trace_reduce.reduce(trace_reduce.load_events(files[0]))
+            events = span_reduce.load_events(files[0])
+            if keep:
+                shutil.copy(files[0], Path(keep) / "trace.xplane.pb")
         finally:
             shutil.rmtree(self.dir, ignore_errors=True)
+        bench = {**events, "spans": [x for x in events["spans"]
+                                     if x[0].startswith(trace_reduce.SPAN_PREFIX)]}
+        scoped = [{**span_reduce.program_scopes(text), "label": label} for label, text in hlo]
+        return trace_reduce.reduce(bench), span_reduce.reduce(events, scoped)
+
+
+@contextlib.contextmanager
+def program_counters(on: bool):
+    """With ``on``, a fresh registry of the program's counters is active in
+    the block, and the dict yielded holds every sample of it once the block
+    ends: ``{name: {"label=value,...": value}}``, a histogram as
+    ``<name>_count`` and ``<name>_sum``. Without, nothing is turned on and
+    the dict stays empty."""
+    samples: dict = {}
+    if not on:
+        yield samples
+        return
+    from repro.obs import metrics
+
+    registry = metrics.enable(metrics.MetricsRegistry())
+    try:
+        yield samples
+    finally:
+        metrics.disable()
+        for fam in registry.families():
+            for labels, child in fam.samples():
+                key = ",".join(f"{k}={v}" for k, v in labels.items())
+                if fam.kind == "histogram":
+                    samples.setdefault(f"{fam.name}_count", {})[key] = child.count
+                    samples.setdefault(f"{fam.name}_sum", {})[key] = child.sum
+                else:
+                    samples.setdefault(fam.name, {})[key] = child.value
 
 
 def limits_for(cell_name: str) -> dict:
@@ -117,16 +177,25 @@ def check(cell, seed: int, run: dict) -> dict:
 
 
 def execute(cell, seed: int, seconds: float, trace: bool, devices, device_info: dict,
-            *, peaks: dict | None = None) -> dict:
-    """Everything after the look for a chip; returns the result object."""
+            *, peaks: dict | None = None, check_outputs: bool = True, keep: str = "") -> dict:
+    """Everything after the look for a chip; returns the result object.
+    Without ``check_outputs`` nothing is compared (``correct`` is None);
+    with ``keep`` a traced run leaves its trace, the compiled HLO of its
+    programs and the values its readers got in that directory."""
+    if keep:
+        Path(keep).mkdir(parents=True, exist_ok=True)
     tracer = Tracer() if trace else None
     driver = train_driver if cell.traffic["kind"] == "train" else serve_driver
-    run = driver.run(cell, seed, seconds, devices, tracer=tracer)
+    with program_counters(trace) as counters:
+        run = driver.run(cell, seed, seconds, devices, tracer=tracer)
+    # lowered again only now: tracing a program counts in the registry
+    hlo = run.pop("programs").texts()
     gc.collect()
-    numbers = check(cell, seed, run)
-    limits = limits_for(cell.name)
+    numbers = check(cell, seed, run) if check_outputs else {}
+    limits = limits_for(cell.name) if check_outputs else {}
     checks = {k: {"value": numbers[k], "limit": limits[k]} for k in limits}
-    correct = all(math.isfinite(c["value"]) and c["value"] <= c["limit"] for c in checks.values())
+    correct = (all(math.isfinite(c["value"]) and c["value"] <= c["limit"]
+                   for c in checks.values()) if check_outputs else None)
 
     for k, v in run["log"].items():
         log(f"[bench] {k}: {v}")
@@ -135,9 +204,15 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices, device_info: 
             log(f"[check] {k[1:]}: {v}")
     device = {**device_info, "memory_peak_bytes": int(run["memory_peak_bytes"])}
     if trace:
-        s = tracer.reduce()
+        s, spans = tracer.reduce(hlo, keep)
         device.update(busy_s=s["busy_s"], window_s=s["window_s"])
-        values = {**run["values"], "trace": s, "peaks": peaks}
+        values = {**run["values"], "trace": s, "spans": spans, "counters": counters,
+                  "peaks": peaks}
+        if keep:
+            for i, (label, text) in enumerate(hlo):
+                (Path(keep) / f"{cell.name}.{i}.{label}.hlo.txt").write_text(text)
+            (Path(keep) / f"{cell.name}.values.json").write_text(
+                json.dumps(values, indent=1, default=str))
         metrics = {}
         for m in cell.per_layer:
             v = metric_reader(m["name"])(values)
@@ -160,21 +235,23 @@ def execute(cell, seed: int, seconds: float, trace: bool, devices, device_info: 
             "metrics": metrics, "device": device, **extra, "checks": checks}
 
 
-def main(argv=None) -> int:
-    ap = argparse.ArgumentParser(prog="run_cell.py")
+def arguments(prog: str) -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser(prog=prog)
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
-    args = ap.parse_args(argv)
+    return ap
 
-    cell = resolve(args.workload)
+
+def on_chip(cell) -> tuple | None:
+    """(devices, their description, peaks) with the compile cache on, or
+    None where the chips are missing or of an unknown kind."""
     try:
         devices, info = chip_devices(cell.chips)
         peaks = peaks_mod.peaks_for(info["kind"])
     except (NoChip, peaks_mod.UnknownDevice) as e:
         log(f"[bench] {e}")
-        return EXIT_NO_CHIP
+        return None
 
     import jax
 
@@ -182,6 +259,19 @@ def main(argv=None) -> int:
 
     log(f"[bench] compile cache: {enable_compile_cache()}")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return devices, info, peaks
+
+
+def main(argv=None) -> int:
+    ap = arguments("run_cell.py")
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+
+    cell = resolve(args.workload)
+    chip = on_chip(cell)
+    if chip is None:
+        return EXIT_NO_CHIP
+    devices, info, peaks = chip
     result = execute(cell, args.seed, args.seconds, bool(args.trace), devices, info,
                      peaks=peaks)
     print(json.dumps(result), flush=True)

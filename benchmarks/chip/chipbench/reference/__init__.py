@@ -7,7 +7,8 @@ missing fails there, before any run. A new architecture brings its reference
 as a new module here and names it in its configuration file; the drivers take
 whatever module the configuration names.
 
-A reference module gives, for training cells (``train_driver``):
+A reference module gives, for training cells (``train_driver``), the
+functions :data:`INTERFACE` lists under ``train``:
 
 - ``init_weights(cfg, seed)``: the weights the program's jitted init makes
   from the seed, in the module's own layout;
@@ -26,7 +27,7 @@ A reference module gives, for training cells (``train_driver``):
 - ``adamw_step(params, m, v, grads, count, traffic)``: ``(params, m, v,
   clip scale)``;
 
-and for serving cells (``serve_driver``):
+and for serving cells (``serve_driver``), those under ``serve``:
 
 - ``init_weights(cfg, seed)``;
 - ``logits(params, tokens, cfg, numerics=)``: ``(B, S, padded vocab)``
@@ -42,6 +43,14 @@ AdamW.
 from __future__ import annotations
 
 import importlib
+
+
+# The functions a reference module gives, by the traffic kind of its cells.
+INTERFACE = {
+    "train": ("init_weights", "leaf_paths", "draw_leaf", "leaf_norms", "change_norms",
+              "loss_and_grads", "adamw_step"),
+    "serve": ("init_weights", "logits"),
+}
 
 
 class MissingReference(LookupError):

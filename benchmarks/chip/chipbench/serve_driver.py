@@ -8,18 +8,24 @@ every request that is due and not yet served, oldest first, up to
 ``per_call``, and sleeps until the next one is due when none is. A request
 is timed from when it was due to when its call returned. Requests due in
 the window that are still waiting when it closes are served after it, up
-to ``drain_s``; one not served by then has failed.
+to ``drain_s``; one not served by then has failed. A traced run notes the
+engine's prefill and decode programs during the warm-up
+(``programs.Noted``), so that their compiled HLO can be read after the
+window.
 """
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import math
 import time
+from unittest import mock
 
 import numpy as np
 
 from . import flops
+from .programs import Noted
 from .reference import load as load_reference
 from .spec import arch_config
 from .traffic import serve_requests
@@ -39,9 +45,14 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
     cfg, t = cell.config, cell.traffic
     engine = ServeEngine(arch_config(cfg), batch=t["batch"], max_seq=t["max_seq"],
                          mesh=make_host_mesh(devices=devices), seed=seed)
+    programs = Noted()
+    noting = contextlib.nullcontext()
+    if tracer is not None:
+        noting = mock.patch.multiple(engine, _prefill=programs.noting("prefill", engine._prefill),
+                                     _decode=programs.noting("decode", engine._decode))
     requests = serve_requests(t, seed, seconds, cfg["vocab_size"])
     gen = int(t["gen_len"])
-    with TraceAnnotation("bench:warmup"):  # two new tokens run every program once
+    with TraceAnnotation("bench:warmup"), noting:  # two new tokens run every program once
         for length in t["prompt_lengths"]:
             engine.serve_requests([np.zeros(length, np.int32)], 2)
     for k in engine.stats:
@@ -105,6 +116,7 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
     decode_steps = batches * gen
     step_s = stats["decode_s"] / decode_steps if decode_steps else math.nan
     prompt = t["prompt_lengths"][0]
+    one_length = len(t["prompt_lengths"]) == 1
     filled = prompt + (gen + 1) / 2  # mean positions a decode step reads, one length only
     e2e = {"serve_tokens_per_s": new_tokens / busy if busy else math.nan,
            "serve_latency_p95_s": p95}
@@ -124,7 +136,11 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
             "useful_row_share": (len(served) * gen / stats["decode_tokens"]
                                  if stats["decode_tokens"] else math.nan),
             "chips": len(devices),
-            "one_prompt_length": len(t["prompt_lengths"]) == 1,
+            "one_prompt_length": one_length,
+            # a prefill call's work, known where every call has one prompt length
+            "prefill_flops_per_call": (t["batch"] * flops.prefill_flops(cfg, prompt)
+                                       if one_length else None),
+            "kernel_work": flops.causal_kernel_work(cfg, t["batch"], prompt) if one_length else {},
         },
         "log": {
             "calls": len(calls), "busy_s": busy, "new_tokens": new_tokens,
@@ -135,6 +151,7 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
         },
         "served": {i: (by_id[i]["prompt"], done[i][1]) for i in done},
         "calls": [(s, e, len(ids)) for s, e, ids in calls],
+        "programs": programs,
     }
 
 

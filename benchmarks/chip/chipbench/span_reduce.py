@@ -13,14 +13,17 @@
 * device idle time by the innermost host span over each gap, and inside each
   span name, with the device's intervals moved onto the host's clock;
 * device self time by the model's ``jax.named_scope`` names, read from the
-  ``op_name`` metadata of the compiled programs' HLO text.
+  ``op_name`` metadata of the compiled programs' HLO text: by the innermost
+  catalogued scope (:data:`SCOPES`), and by every name on an op's path, so
+  that a scope the program adds is read by its name with no list to extend;
+* device self time and calls of every operation by name, a Pallas kernel
+  by its kernel's name, and the runs and device time of each program.
 
 :func:`reduce` returns only keys :func:`trace_reduce.reduce` does not.
 """
 
 from __future__ import annotations
 
-import bisect
 import heapq
 import re
 import statistics
@@ -112,11 +115,20 @@ def innermost(spans, points) -> list:
     return out
 
 
-def innermost_scope(op_name: str) -> str | None:
+def innermost_scope(op_name: str | None) -> str | None:
     """The last of :data:`SCOPES` in an ``op_name`` path, through transforms:
     ``jit(step)/transpose(jvp(attention))/dot_general`` -> ``attention``."""
-    found = [p for p in re.split(r"[/()]", op_name) if p in SCOPES]
+    found = [p for p in re.split(r"[/()]", op_name or "") if p in SCOPES]
     return found[-1] if found else None
+
+
+def scope_path(op_name: str | None) -> tuple:
+    """Every name on an ``op_name`` path but the operation at its end, each
+    out of the transforms that wrap it, once:
+    ``jit(step)/transpose(jvp(mlp))/experts/dot_general`` -> ``("step",
+    "mlp", "experts")``."""
+    parts = [p.split("(")[-1].rstrip(")") for p in (op_name or "").split("/")[:-1]]
+    return tuple(dict.fromkeys(p for p in parts if p))
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) .*\{$")
@@ -126,15 +138,18 @@ _CALLS = re.compile(r"calls=%([\w.\-]+)")
 
 
 def program_scopes(hlo: str) -> dict:
-    """{"module": name, "scope": {instruction: scope}} from compiled HLO text.
+    """{"module": name, "scope": {instruction: scope}, "path": {instruction:
+    names}} from compiled HLO text: :func:`innermost_scope` and
+    :func:`scope_path` of the ``op_name`` each instruction is read by.
 
-    An instruction of a computation that is not a fusion's body takes the
-    innermost scope of its own ``op_name``. XLA gives a fusion the metadata
-    of the op it is built round, so a fusion under no scope takes that of
-    its fused computation's root, and failing that ``kv_cache_write`` where
-    any instruction fused in it is under that scope: the scan's write-back
-    of each layer's cache into the stacked cache is the fusion that makes
-    the one-hot update, and its own ``op_name`` is the scan's."""
+    An instruction of a computation that is not a fusion's body is read by
+    its own ``op_name``. XLA gives a fusion the metadata of the op it is
+    built round, so a fusion under no scope is read by its fused
+    computation's root, and failing that by an instruction fused in it that
+    is under ``kv_cache_write``: the scan's write-back of each layer's cache
+    into the stacked cache is the fusion that makes the one-hot update, and
+    its own ``op_name`` is the scan's. An instruction under no scope by
+    these rules keeps the names on its own path, else its root's."""
     module = ""
     comps: dict = {}
     cur = None
@@ -150,7 +165,7 @@ def program_scopes(hlo: str) -> dict:
         if m and cur is not None:
             name = m.group(2)
             op = _OP_NAME.search(line)
-            cur["ops"][name] = innermost_scope(op.group(1)) if op else None
+            cur["ops"][name] = op.group(1) if op else None
             if m.group(1):
                 cur["root"] = name
             if " fusion(" in line:
@@ -158,21 +173,21 @@ def program_scopes(hlo: str) -> dict:
                 if calls:
                     cur["calls"][name] = calls.group(1)
     fused = {c for comp in comps.values() for c in comp["calls"].values()}
-    scope = {}
+    scope, path = {}, {}
     for cname, comp in comps.items():
         if cname in fused:
             continue
         for name, own in comp["ops"].items():
             body = comps.get(comp["calls"].get(name, ""))
-            if own or body is None:
-                scope[name] = own
-            elif body["ops"].get(body["root"]):
-                scope[name] = body["ops"][body["root"]]
-            elif "kv_cache_write" in body["ops"].values():
-                scope[name] = "kv_cache_write"
-            else:
-                scope[name] = None
-    return {"module": module, "scope": scope}
+            root = body["ops"].get(body["root"]) if body else None
+            read = own if innermost_scope(own) else None
+            if read is None and body is not None:
+                read = root if innermost_scope(root) else next(
+                    (o for o in body["ops"].values()
+                     if innermost_scope(o) == "kv_cache_write"), None)
+            read = read or own or root
+            scope[name], path[name] = innermost_scope(read), scope_path(read)
+    return {"module": module, "scope": scope, "path": path}
 
 
 def _match_programs(seen: dict, programs: list) -> dict:
@@ -189,17 +204,13 @@ def _match_programs(seen: dict, programs: list) -> dict:
     return out
 
 
-def scope_ops(ops, modules, programs: list) -> list:
-    """(program, scope, instruction, start, end) for the ops of one device:
-    the program is the matched one's ``label`` (else the trace's module
-    name), the scope ``-`` where none is found."""
-    mods = sorted(modules, key=lambda x: x[1])
-    starts = [s for _, s, _ in mods]
-
-    def module_of(s):
-        i = bisect.bisect_right(starts, s) - 1
-        return mods[i][0] if i >= 0 and mods[i][2] >= s else None
-
+def scope_ops(ops, modules, programs: list) -> tuple:
+    """([(program, scope, instruction, start, end, path)] for the ops of one
+    device, {trace module name: program} as matched). The program is the
+    matched one's ``label`` (else the trace's module name), the scope ``-``
+    where none is found, the path the names of :func:`scope_path` (none
+    where no program matched)."""
+    module_of = tr.module_at(modules)
     placed = [(module_of(s), tr.short_name(n), s, e) for n, s, e in ops]
     seen = defaultdict(set)
     for mod, name, _, _ in placed:
@@ -210,8 +221,14 @@ def scope_ops(ops, modules, programs: list) -> list:
     for mod, name, s, e in placed:
         prog = match.get(mod)
         scope = (prog["scope"].get(name) if prog else None) or UNSCOPED
-        out.append((prog.get("label", mod) if prog else mod, scope, name, s, e))
-    return out
+        path = prog.get("path", {}).get(name, ()) if prog else ()
+        out.append((prog.get("label", mod) if prog else mod, scope, name, s, e, path))
+    return out, match
+
+
+def _inside(s: float, e: float, lo: float, hi: float) -> float:
+    """The share of the interval (s, e) that lies inside (lo, hi)."""
+    return (min(e, hi) - max(s, lo)) / (e - s) if e > s else 1.0
 
 
 def reduce(events: dict, programs: list = (), window: tuple | None = None,
@@ -221,12 +238,19 @@ def reduce(events: dict, programs: list = (), window: tuple | None = None,
     ``span_stats`` (count and seconds of the spans of each name inside the
     window), ``idle_under`` (device idle seconds by the innermost span over
     each gap's midpoint, ``host`` where none), ``idle_inside`` (device idle
-    seconds inside the spans of each name, nested spans included) and
+    seconds inside the spans of each name, nested spans included),
     ``scope_self_s`` (device self seconds by named scope, ``-`` for ops under
-    none) and ``scope_ops`` (the ``top`` ops by self time, as
-    ``program/instruction [scope]``). Device seconds are averaged over
-    devices. ``programs`` are :func:`program_scopes` of the programs the
-    trace ran, each with a ``label``."""
+    none), ``scope_incl_s`` (device self seconds of the ops under each name
+    of :func:`scope_path`, nested scopes included), ``scope_ops`` (the
+    ``top`` ops by self time, as ``program/instruction [scope]``),
+    ``op_self_s`` and ``op_calls`` (device self seconds and calls of every
+    op by instruction name, over all programs), ``program_runs`` and
+    ``program_s`` (runs and device seconds of each program by its ``label``,
+    else by the trace's module name). An op or a run that the window cuts
+    counts by the share of it inside. Device
+    seconds and counts are averaged over devices. ``programs`` are
+    :func:`program_scopes` of the programs the trace ran, each with a
+    ``label``."""
     lo, hi = window or tr.window_of(events)
     offset, bound, pairs = clock_offset(events.get("runs", {}))
     log(f"[bench] clock offset {offset / 1e6!r} ms (host = device + offset; "
@@ -242,7 +266,9 @@ def reduce(events: dict, programs: list = (), window: tuple | None = None,
         by_name[n].append((s, e))
     devs = sorted(events["devices"])
     under, inside = defaultdict(float), defaultdict(float)
-    scope_t, op_t = defaultdict(float), defaultdict(float)
+    scope_t, incl_t, op_t = defaultdict(float), defaultdict(float), defaultdict(float)
+    op_self, op_calls, runs = defaultdict(float), defaultdict(float), defaultdict(float)
+    run_t = defaultdict(float)
     for d in devs:
         ops = [(n, s + offset, e + offset) for n, s, e in events["devices"][d]
                if e + offset > lo and s + offset < hi]
@@ -254,12 +280,21 @@ def reduce(events: dict, programs: list = (), window: tuple | None = None,
             inside[n] += (tr.length(merged) - tr.length(tr.subtract(merged, gaps))) / 1e9
         mods = [(m, s + offset, e + offset) for m, s, e in events.get("modules", {}).get(d, ())]
         clipped = [(n, max(s, lo), min(e, hi)) for n, s, e in ops]
-        scoped = scope_ops(clipped, mods, list(programs))
-        for scope, t in tr.self_times([(f"%{sc}", s, e) for _, sc, _, s, e in scoped]).items():
+        scoped, match = scope_ops(clipped, mods, list(programs))
+        own = tr.self_each([(s, e) for _, _, _, s, e, _ in scoped])
+        for (prog, scope, name, _, _, path), t, (_, s, e) in zip(scoped, own, ops):
             scope_t[scope] += t / 1e9
-        labelled = [(f"%{p}/{op} [{sc}]", s, e) for p, sc, op, s, e in scoped]
-        for op, t in tr.self_times(labelled).items():
-            op_t[op] += t / 1e9
+            op_t[f"{prog}/{name} [{scope}]"] += t / 1e9
+            for p in path:
+                incl_t[p] += t / 1e9
+            op_self[name] += t / 1e9
+            op_calls[name] += _inside(s, e, lo, hi)
+        for m, s, e in mods:
+            if e > lo and s < hi:
+                prog = match.get(m)
+                label = prog.get("label", m) if prog else m.split("(")[0]
+                runs[label] += _inside(s, e, lo, hi)
+                run_t[label] += (min(e, hi) - max(s, lo)) / 1e9
     n = max(1, len(devs))
     desc = lambda d: {k: v / n for k, v in sorted(d.items(), key=lambda kv: -kv[1])}  # noqa: E731
     return {
@@ -269,7 +304,12 @@ def reduce(events: dict, programs: list = (), window: tuple | None = None,
         "idle_under": desc(under),
         "idle_inside": desc(inside),
         "scope_self_s": desc(scope_t) if programs else {},
+        "scope_incl_s": desc(incl_t),
         "scope_ops": [[k, v] for k, v in desc(op_t).items()][:top] if programs else [],
+        "op_self_s": desc(op_self),
+        "op_calls": {k: v / n for k, v in sorted(op_calls.items())},
+        "program_runs": {k: v / n for k, v in sorted(runs.items())},
+        "program_s": {k: v / n for k, v in sorted(run_t.items())},
     }
 
 

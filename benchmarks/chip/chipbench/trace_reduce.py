@@ -48,26 +48,45 @@ def short_name(op: str, module: str | None = None) -> str:
     return f"{module.split('(')[0]}/{name}" if module else name
 
 
-def self_times(ops, modules=()) -> dict:
-    """Time of each op not covered by the ops nested inside it (a loop's
-    body runs inside the loop's own event), by :func:`short_name`. An op that
-    only overlaps another, as an asynchronous collective overlaps compute,
-    is not nested in it."""
-    mods = sorted(modules, key=lambda x: x[1])
-    starts = [s for _, s, _ in mods]
-    out = defaultdict(float)
-    stack = []  # [name, end, nested time, own time]
-    for name, s, e in sorted(ops, key=lambda x: (x[1], -x[2])):
+def self_each(intervals) -> list:
+    """The self time of each (start, end) interval, in their order: its
+    length less that of the intervals nested inside it (a loop's body runs
+    inside the loop's own event). An interval that only overlaps another, as
+    an asynchronous collective overlaps compute, is not nested in it."""
+    out = [0.0] * len(intervals)
+    stack = []  # [index, end, nested time]
+    for i in sorted(range(len(intervals)), key=lambda i: (intervals[i][0], -intervals[i][1])):
+        s, e = intervals[i]
         while stack and stack[-1][1] <= s:
-            top = stack.pop()
-            out[top[0]] += top[3] - top[2]
-        i = bisect.bisect_right(starts, s) - 1
-        module = mods[i][0] if i >= 0 and mods[i][2] >= s else None
+            j, _, nested = stack.pop()
+            out[j] = intervals[j][1] - intervals[j][0] - nested
         if stack and e <= stack[-1][1]:
             stack[-1][2] += e - s
-        stack.append([short_name(name, module), e, 0.0, e - s])
-    for top in stack:
-        out[top[0]] += top[3] - top[2]
+        stack.append([i, e, 0.0])
+    for j, _, nested in stack:
+        out[j] = intervals[j][1] - intervals[j][0] - nested
+    return out
+
+
+def module_at(modules):
+    """A function from a time to the name of the program run (``modules``:
+    (name, start, end)) that holds it, else None."""
+    mods = sorted(modules, key=lambda x: x[1])
+    starts = [s for _, s, _ in mods]
+
+    def at(t):
+        i = bisect.bisect_right(starts, t) - 1
+        return mods[i][0] if i >= 0 and mods[i][2] >= t else None
+
+    return at
+
+
+def self_times(ops, modules=()) -> dict:
+    """:func:`self_each` of the ops, summed by :func:`short_name`."""
+    module_of = module_at(modules)
+    out = defaultdict(float)
+    for (name, s, _), t in zip(ops, self_each([(s, e) for _, s, e in ops])):
+        out[short_name(name, module_of(s))] += t
     return out
 
 

@@ -11,7 +11,9 @@ it, with three things of the benchmark's put in its way:
   reads the losses of the first steps, the optimizer's state after step 1
   and the weights after the last checked step, as they come out of the very
   step the window then times. The wrapper adds a host span per call and
-  nothing else to the timed path.
+  nothing else to the timed path. In a traced run its first call also
+  notes the step's abstract arguments (``programs.Noted``), so that its
+  compiled HLO can be read after the window.
 
 The window opens at the first loss the loop pulls (every ``log_every``
 steps), after the step has compiled, and closes at the first pull at least
@@ -28,6 +30,7 @@ from unittest import mock
 import numpy as np
 
 from . import flops
+from .programs import Noted
 from .reference import load as load_reference
 from .spec import arch_config
 from .traffic import train_rows
@@ -90,9 +93,10 @@ def _change_norms(params, cfg: dict, seed: int) -> dict:
 class _StepRecorder:
     """Stands in for ``jax`` inside ``repro.launch.train``: ``jit`` of the
     train step (the only call with ``donate_argnums``) returns the step
-    wrapped to record the checked readings."""
+    wrapped to record the checked readings, and with ``capture`` noted in
+    ``programs`` on its first call."""
 
-    def __init__(self, real_jax, cfg: dict, traffic: dict, seed: int):
+    def __init__(self, real_jax, cfg: dict, traffic: dict, seed: int, capture: bool = False):
         self._jax = real_jax
         self.cfg, self.traffic, self.seed = cfg, traffic, seed
         self.checked = int(traffic["checked_steps"])
@@ -100,6 +104,7 @@ class _StepRecorder:
         self.losses: list = []
         self.grad_norms: dict = {}
         self.change_norms: dict = {}
+        self.capture, self.programs = capture, Noted()
 
     def __getattr__(self, name):
         return getattr(self._jax, name)
@@ -111,6 +116,8 @@ class _StepRecorder:
         annotate = self._jax.profiler.TraceAnnotation
 
         def step(state, batch):
+            if self.capture and self.calls == 0:
+                self.programs.note("train_step", jitted, (state, batch))
             with annotate("bench:train_step"):
                 state, metrics = jitted(state, batch)
             self.calls += 1
@@ -143,7 +150,7 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
             "--lr", str(t["lr"]), "--warmup", str(t["warmup"]), "--seed", str(seed),
             "--log-every", str(t["log_every"])]
     args = train_mod.build_argparser().parse_args(argv)
-    recorder = _StepRecorder(jax, cfg, t, seed)
+    recorder = _StepRecorder(jax, cfg, t, seed, capture=tracer is not None)
     stamps: list = []  # (time, step, loss) at each pull of the loss
 
     def on_metrics(m: dict) -> None:
@@ -191,12 +198,15 @@ def run(cell, seed: int, seconds: float, devices, tracer=None) -> dict:
         "values": {
             "train_tokens_per_s": traced_rate,
             "flops_per_token": flops.train_flops_per_token(cfg, t["seq"]),
+            "kernel_work": flops.causal_kernel_work(cfg, t["global_batch"] // len(devices),
+                                                    t["seq"]),
             "chips": len(devices),
         },
         "log": {"window_steps": [s0, s1], "window_losses": losses,
                 "step_s": window_s / max(1, s1 - s0)},
         "program": {"losses": recorder.losses, "grad_norms": recorder.grad_norms,
                     "change_norms": recorder.change_norms},
+        "programs": recorder.programs,
     }
 
 

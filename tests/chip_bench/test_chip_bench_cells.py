@@ -20,14 +20,14 @@ TRAIN, OFFLINE = "minicpm3-4b.train-4k", "mistral-7b.serve-offline-4k"
 SEED = 2**31 + 101
 
 
-def _execute(name: str, seconds: float = 0.5):
+def _execute(name: str, seconds: float = 0.5, trace: bool = False, **kw):
     import jax
 
     cell = resolve(name, smoke=True)
     devices = jax.devices()[: cell.chips]
     info = {"platform": devices[0].platform, "kind": devices[0].device_kind,
             "count": len(devices)}
-    return harness.execute(cell, SEED, seconds, False, devices, info)
+    return harness.execute(cell, SEED, seconds, trace, devices, info, **kw)
 
 
 def _assert_result_shape(result: dict, metrics: set):
@@ -46,6 +46,60 @@ def test_cell_runs_correct_at_smoke_size(name, metric):
     _assert_result_shape(result, {metric, "setup_s"})
     assert result["correct"], result["checks"]
     assert result["attempted"] > 0 and result["failed"] == 0
+
+
+def test_a_traced_run_reads_the_program_counters(tmp_path):
+    """The registry is on for a traced run alone: the attention path the
+    program counts while it traces its programs reaches the readers'
+    values, and the registry is off again afterwards."""
+    from repro.obs import metrics
+
+    result = _execute(OFFLINE, trace=True, keep=str(tmp_path),
+                      peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
+    assert result["correct"], result["checks"]
+    values = json.loads((tmp_path / f"{OFFLINE}.values.json").read_text())
+    paths = values["counters"]["nbi_attention_path_total"]
+    assert paths.get("path=chunked", 0) > 0  # the CPU takes no fused kernel
+    assert metrics.get_registry() is metrics.NULL_REGISTRY
+
+
+@pytest.mark.parametrize("name,labels", [(TRAIN, ["train_step"]),
+                                         (OFFLINE, ["prefill", "decode"])])
+def test_a_traced_run_notes_its_programs_in_set_up_alone(monkeypatch, name, labels):
+    """The programs whose HLO is read after the window are noted before it
+    opens; the window calls them as an untraced run does."""
+    from chipbench import programs
+
+    seen, note, start = [], programs.Noted.note, harness.Tracer.start
+
+    def noting(self, label, jitted, args):
+        seen.append((label, bool(opened)))
+        note(self, label, jitted, args)
+
+    def starting(self):
+        opened.append(True)
+        start(self)
+
+    opened: list = []
+    monkeypatch.setattr(programs.Noted, "note", noting)
+    monkeypatch.setattr(harness.Tracer, "start", starting)
+    assert _execute(name, trace=True,
+                    peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})["correct"]
+    assert opened and sorted(dict.fromkeys(x for x, _ in seen)) == sorted(labels)
+    assert all(not was_open for _, was_open in seen)
+
+
+def test_an_untraced_run_turns_nothing_on(monkeypatch):
+    """No registry enabled and no program noted for its HLO."""
+    from chipbench import programs
+    from repro.obs import metrics
+
+    seen = []
+    monkeypatch.setattr(metrics, "enable", lambda *a, **kw: seen.append("enable"))
+    monkeypatch.setattr(programs.Noted, "note", lambda *a, **kw: seen.append("program"))
+    for name in (TRAIN, OFFLINE):
+        assert _execute(name)["correct"]
+    assert seen == []
 
 
 def test_open_loop_traffic_is_served_at_smoke_size():

@@ -31,10 +31,60 @@ INTERFACE = ("init_weights", "leaf_paths", "draw_leaf", "leaf_norms", "change_no
 
 @pytest.mark.parametrize("smoke", [False, True])
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
-def test_each_configuration_names_the_decoder(cell, smoke):
-    config = spec.resolve(cell, smoke=smoke).config
-    assert config["reference"] == "decoder"
-    assert reference.load(config) is decoder
+def test_each_configuration_names_a_reference_with_its_interface(cell, smoke):
+    """The module a configuration names loads, and gives every function the
+    drivers call for the cell's kind of traffic."""
+    c = spec.resolve(cell, smoke=smoke)
+    module = reference.load(c.config)
+    assert module.__name__ == f"chipbench.reference.{c.config['reference']}"
+    missing = [f for f in reference.INTERFACE[c.traffic["kind"]]
+               if not callable(getattr(module, f, None))]
+    assert not missing, f"{module.__name__} lacks {missing}"
+
+
+def test_a_new_architecture_is_added_as_new_files(tmp_path):
+    """A copy of the benchmark that adds a configuration naming a reference
+    module of its own (a re-export of ``decoder``), its cell, its limits and
+    its names in the metrics' ``workloads``: new files and appended entries
+    alone. The copy's own tests of the contract, of each cell's files and of
+    each reference's interface pass, the new cell's cases among them."""
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    for p in SPEC["paths"]:
+        shutil.copytree(ROOT / p, tmp_path / p,
+                        ignore=shutil.ignore_patterns("__pycache__", ".jax_cache"))
+    bench = tmp_path / "benchmarks" / "chip"
+    (bench / "chipbench" / "reference" / "stubref.py").write_text(
+        '"""A stand-in architecture\'s reference."""\n\n'
+        "from .decoder import *  # noqa: F401,F403\n")
+    train = next(w for w in SPEC["workloads"] if w["name"] == TRAIN)
+    base = next(c for c in SPEC["configs"] if c["name"] == train["config"])
+    config = json.loads((ROOT / base["file"]).read_text())
+    (bench / "configs" / "stub.json").write_text(
+        json.dumps({**config, "name": "stub", "reference": "stubref"}))
+    shutil.copy(bench / "workloads" / f"{TRAIN}.json", bench / "workloads" / "stub.train-4k.json")
+    new = json.loads(json.dumps(SPEC))
+    new["configs"].append({**base, "name": "stub", "file": "benchmarks/chip/configs/stub.json"})
+    new["workloads"].append({**train, "name": "stub.train-4k", "config": "stub"})
+    for m in new["end_to_end"] + new["per_layer"]:
+        if TRAIN in m.get("workloads", ()):
+            m["workloads"].append("stub.train-4k")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(new, indent=1))
+    tests = ["tests/chip_bench/test_chip_bench_spec.py::test_contract_shape",
+             "tests/chip_bench/test_chip_bench_spec.py::test_every_cell_resolves_to_its_files",
+             "tests/chip_bench/test_chip_bench_reference_choice.py::"
+             "test_each_configuration_names_a_reference_with_its_interface"]
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-rA", "-p", "no:cacheprovider", *tests],
+        cwd=tmp_path, capture_output=True, text=True, timeout=240,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(ROOT / "src")})
+    assert out.returncode == 0, out.stdout[-4000:] + out.stderr[-2000:]
+    cases = len(SPEC["workloads"]) + 1
+    assert f"{1 + cases + 2 * cases} passed" in out.stdout, out.stdout[-4000:]
+    passed = [line for line in out.stdout.splitlines() if line.startswith("PASSED ")]
+    for case in ("test_every_cell_resolves_to_its_files[stub.train-4k]",
+                 "test_each_configuration_names_a_reference_with_its_interface[stub.train-4k-False]",
+                 "test_each_configuration_names_a_reference_with_its_interface[stub.train-4k-True]"):
+        assert any(line.endswith(f"::{case}") for line in passed), out.stdout[-4000:]
 
 
 def _config_file(tmp_path, name: str, **changes) -> dict:

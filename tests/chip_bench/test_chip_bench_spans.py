@@ -1,8 +1,10 @@
 """The program's spans and scopes in a profiler trace: one clock for host and
-device, idle time by innermost span, device time by named scope, and the
-readers of the metrics they feed."""
+device, idle time by innermost span, device time by named scope and by
+operation, and the readers of the metrics they feed."""
 
+import json
 import random
+import shutil
 import sys
 from pathlib import Path
 
@@ -11,7 +13,7 @@ import pytest
 BENCH = Path(__file__).resolve().parents[2] / "benchmarks" / "chip"
 sys.path.insert(0, str(BENCH))
 
-import trace_cell  # noqa: E402
+from chipbench import flops, harness, spec  # noqa: E402
 from chipbench import span_reduce as sr  # noqa: E402
 from chipbench import trace_reduce as tr  # noqa: E402
 
@@ -175,8 +177,8 @@ def test_device_self_time_by_scope_tells_programs_of_one_name_apart():
                                                "-": 0.003, "attention": 0.002})
     assert r["scope_ops"][:2] == [["decode/fusion.3 [kv_cache_write]", pytest.approx(0.004)],
                                   ["decode/while.7 [-]", pytest.approx(0.003)]]
-    placed = sr.scope_ops(ops, modules, programs)
-    assert [(p, s) for p, s, n, _, _ in placed if n == "fusion.3"] == [
+    placed, _ = sr.scope_ops(ops, modules, programs)
+    assert [(p, s) for p, s, n, _, _, _ in placed if n == "fusion.3"] == [
         ("decode", "kv_cache_write"), ("prefill", "mlp")]
 
 
@@ -186,48 +188,203 @@ def test_scopes_are_the_programs_catalogue():
     assert all(f"``{s}``" in spans.__doc__ for s in sr.SCOPES)
 
 
+def _two_programs(hlo: str = HLO, window=None):
+    """The events of the test above: a decode program's run 0-10 ms and a
+    prefill program's 11-16 ms, both named ``jit_wrapped``."""
+    other = sr.program_scopes(hlo.replace("calls=%fused_computation.1", "calls=%fused_computation")
+                              .replace("%dot.4", "%negate.9").replace("dot(", "negate("))
+    programs = [dict(sr.program_scopes(hlo), label="decode"), dict(other, label="prefill")]
+    op = lambda n, s, e: (f"%{n} = f32[8] op()", s * MS, e * MS)  # noqa: E731
+    ops = [op("while.7", 0, 10), op("fusion", 1, 3), op("fusion.3", 3, 7), op("dot.4", 7, 8),
+           op("fusion.3", 12, 14), op("negate.9", 14, 15)]
+    modules = [("jit_wrapped(1)", 0, 10 * MS), ("jit_wrapped(2)", 11 * MS, 16 * MS)]
+    events = {"devices": {0: ops}, "modules": {0: modules},
+              "spans": [("bench:window", 0, 16 * MS)]}
+    return sr.reduce(events, programs, window=window)
+
+
+def test_scope_path_names_every_scope_through_transforms():
+    assert sr.scope_path("jit(step)/transpose(jvp(mlp))/experts/dot_general") == (
+        "step", "mlp", "experts")
+    assert sr.scope_path("jit(f)/attention/causal_flash_fwd/pallas_call") == (
+        "f", "attention", "causal_flash_fwd")
+    assert sr.scope_path(None) == () and sr.scope_path("add") == ()
+    p = sr.program_scopes(HLO)
+    assert p["path"]["fusion"] == ("wrapped", "while", "body", "mlp")
+    # read by the fused instruction under kv_cache_write, as its scope is
+    assert p["path"]["fusion.3"] == ("wrapped", "while", "body", "decode_attention",
+                                     "kv_cache_write")
+    assert p["path"]["while.7"] == ()
+
+
+def test_inclusive_scope_time_reads_a_scope_no_list_names():
+    """A scope the catalogue does not hold (``experts``, inside ``mlp``) is
+    read by its name, and leaves the ``mlp`` reading as it was."""
+    plain = _two_programs()
+    nested = _two_programs(HLO.replace("/mlp/mul", "/mlp/experts/mul"))
+    assert "experts" not in sr.SCOPES and "experts" not in plain["scope_incl_s"]
+    assert nested["scope_incl_s"]["experts"] == pytest.approx(0.004)
+    for r in (plain, nested):
+        assert r["scope_incl_s"]["mlp"] == pytest.approx(0.004)
+        assert r["scope_incl_s"]["kv_cache_write"] == pytest.approx(0.004)
+        # the cache write lies inside decode_attention, nested scopes included
+        assert r["scope_incl_s"]["decode_attention"] == pytest.approx(0.004)
+        assert r["scope_incl_s"]["attention"] == pytest.approx(0.002)
+        assert r["scope_incl_s"]["wrapped"] == pytest.approx(0.010)
+    assert nested["scope_self_s"] == plain["scope_self_s"]
+
+
+@pytest.mark.parametrize("window,calls,runs", [
+    (None, {"fusion.3": 2.0, "negate.9": 1.0}, {"decode": 1.0, "prefill": 1.0}),
+    # a window to 14.5 ms cuts the prefill run (11-16 ms) and its negate.9 (14-15 ms)
+    ((0, 14.5 * MS), {"fusion.3": 2.0, "negate.9": 0.5}, {"decode": 1.0, "prefill": 0.7}),
+])
+def test_every_operation_and_program_run_is_counted(window, calls, runs):
+    r = _two_programs(window=window)
+    assert r["op_calls"] == pytest.approx({"while.7": 1.0, "fusion": 1.0, "dot.4": 1.0, **calls})
+    assert r["program_runs"] == pytest.approx(runs)
+    # device seconds of each program's runs: the prefill run is 5 ms, cut to 3.5
+    assert r["program_s"] == pytest.approx({"decode": 0.010,
+                                            "prefill": 0.005 * runs["prefill"]})
+    assert r["op_self_s"]["fusion.3"] == pytest.approx(0.006)
+    assert r["op_self_s"]["negate.9"] == pytest.approx(calls["negate.9"] * 0.001)
+    assert r["op_self_s"]["while.7"] == pytest.approx(0.003)
+
+
+def _nested(rng, s, e, depth, out):
+    """Intervals nested inside (s, e) at random, each with the length of
+    the intervals directly inside it, by the plain rule."""
+    cuts = sorted(rng.uniform(s, e) for _ in range(2 * rng.randint(0, 3 if depth < 3 else 0)))
+    i = len(out)
+    out.append([s, e, e - s])
+    for a, b in zip(cuts[::2], cuts[1::2]):
+        out[i][2] -= b - a
+        _nested(rng, a, b, depth + 1, out)
+
+
+@pytest.mark.parametrize("seed", [3, 4, 5])
+def test_self_each_is_the_plain_rule_on_nested_intervals(seed):
+    rng = random.Random(seed)
+    want = []
+    for k in range(20):
+        _nested(rng, 100.0 * k, 100.0 * k + rng.uniform(1, 90), 0, want)
+    order = list(range(len(want)))
+    rng.shuffle(order)
+    got = tr.self_each([(want[i][0], want[i][1]) for i in order])
+    assert got == pytest.approx([want[i][2] for i in order])
+    named = [(f"%op{i % 7}", want[i][0], want[i][1]) for i in order]
+    sums = {}
+    for (n, _, _), t in zip(named, got):
+        sums[n[1:]] = sums.get(n[1:], 0.0) + t
+    assert dict(tr.self_times(named)) == pytest.approx(sums)
+
+
+# the driver's values that the accepted readers take besides the trace
+DRIVER = {"flops_per_token": 4.7e9, "train_tokens_per_s": 17900.0, "chips": 1,
+          "useful_flops_per_s": 3.4e13, "decode_step_s": 0.023, "decode_step_flops": 6.7e10,
+          "decode_step_bytes": 9.5e9, "one_prompt_length": True}
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+ACCEPTED = ["train_mfu", "device_idle_share.train", "serve_mfu.offline", "decode_roofline",
+            "decode_step_ms", "device_idle_share.offline"]
+
+
+@pytest.mark.parametrize("name", ACCEPTED)
+def test_readers_keep_their_values_under_the_new_loading(name):
+    """The harness loads the trace once with ``span_reduce``'s loader and
+    gives ``trace_reduce`` the ``bench:`` spans alone: on the recorded trace
+    its values, and what each accepted reader reads from them, are those of
+    the loader the harness used before."""
+    old = tr.reduce(tr.load_events(str(RECORDED)))
+    tracer = harness.Tracer()
+    shutil.copy(RECORDED, Path(tracer.dir) / "trace.xplane.pb")
+    new, spans = tracer.reduce()
+    assert not Path(tracer.dir).exists()
+    assert new == old
+    assert spans["span_stats"]["bench:step"]["count"] == 3
+    read = spec.metric_reader(name)
+    before = read({**DRIVER, "trace": old, "peaks": PEAKS})
+    assert before is not None
+    assert read({**DRIVER, "trace": new, "spans": spans, "counters": {}, "peaks": PEAKS}) == before
+
+
 def _values(**spans):
     base = {"span_stats": {"nbi:serve.decode_step": {"count": 512, "s": 1.0},
                            "nbi:serve.prefill": {"count": 2, "s": 5.0}},
             "idle_inside": {"nbi:serve.decode": 1.024},
-            "scope_self_s": {"kv_cache_write": 3.072, "attention": 2.5}}
+            "scope_incl_s": {"kv_cache_write": 3.072, "attention": 2.5},
+            "program_runs": {"train_step": 25.0, "prefill": 2.0},
+            "program_s": {"train_step": 11.4, "prefill": 4.0},
+            "op_self_s": {"causal_flash_fwd.17": 0.5, "causal_flash_fwd.18": 0.5,
+                          "transpose_jvp_causal_flash_dq__.3": 1.0, "causal_flash_dkv.11": 1.0,
+                          "fusion.3": 9.0},
+            "op_calls": {"causal_flash_fwd.17": 100.0, "causal_flash_fwd.18": 100.0,
+                         "transpose_jvp_causal_flash_dq__.3": 100.0,
+                         "causal_flash_dkv.11": 100.0, "fusion.3": 100.0}}
     base.update(spans)
-    return {"spans": base, "trace": {"busy_s": 10.0}, "chips": 1,
-            "peaks": {"bf16_flops_per_s": 200e12}, "prefill_flops_per_call": 250e12}
+    # a call of fwd needs 1 ms at the peak, dq 4 ms of bytes at HBM bandwidth, dkv 2 ms
+    work = {"causal_flash_fwd": [197e9, 1.0], "causal_flash_dq": [1.0, 3.276e9],
+            "causal_flash_dkv": [394e9, 1.0]}
+    return {"spans": base, "trace": {"busy_s": 10.0}, "chips": 1, "peaks": PEAKS,
+            "prefill_flops_per_call": 250e12, "kernel_work": work,
+            "counters": {"nbi_attention_path_total": {"path=fused": 3.0, "path=chunked": 1.0}}}
+
+
+NEW = {"decode_host_gap_ms": 2.0, "decode_cache_write_ms": 6.0,
+       # the prefill program's device time, not its host spans' 5 s
+       "prefill_mfu.offline": 100.0 * (250e12 * 2 / 4.0) / 197e12,
+       "train_attention_ms": 100.0, "attention_fused_share.train": 75.0,
+       # 200 fwd calls of 1 ms, 100 dq of 4 ms and 100 dkv of 2 ms in 3 s
+       "causal_flash_roofline": 100.0 * (0.2 + 0.4 + 0.2) / 3.0,
+       "causal_flash_roofline.offline": 100.0 * (0.2 + 0.4 + 0.2) / 3.0}
 
 
 def test_readers_on_synthetic_values():
     v = _values()
-    assert trace_cell.decode_host_gap_ms(v) == pytest.approx(2.0)
-    assert trace_cell.decode_cache_write_ms(v) == pytest.approx(6.0)
-    assert trace_cell.prefill_mfu(v) == pytest.approx(50.0)
-    assert trace_cell.train_attention_share(v) == pytest.approx(25.0)
+    assert {name: spec.metric_reader(name)(v) for name in NEW} == pytest.approx(NEW)
+    only_fwd = _values(op_self_s={"causal_flash_fwd.5": 0.25},
+                       op_calls={"causal_flash_fwd.5": 100.0})
+    assert spec.metric_reader("causal_flash_roofline.offline")(only_fwd) == pytest.approx(40.0)
 
 
 def test_readers_find_nothing_in_a_trace_without_program_spans():
-    """A program without ``nbi:`` spans or scopes: every reader gives None."""
+    """A program without ``nbi:`` spans, scopes, kernels or counters: every
+    reader gives None."""
     v = _values(span_stats={"bench:serve_requests": {"count": 2, "s": 16.0}},
-                idle_inside={}, scope_self_s={})
-    assert all(read(v) is None for read in trace_cell.READERS.values())
+                idle_inside={}, scope_incl_s={}, program_runs={"jit_wrapped": 2.0},
+                program_s={"jit_wrapped": 4.0},
+                op_self_s={"fusion.3": 9.0}, op_calls={"fusion.3": 100.0})
+    v["counters"] = {}
+    assert {name: spec.metric_reader(name)(v) for name in NEW} == dict.fromkeys(NEW)
 
 
-def test_trace_cell_on_the_cpu(tmp_path):
-    """The offline cell at smoke size: the engine's programs are captured,
-    their HLO read back, and the program's spans counted in the window."""
+def test_trace_cell_on_the_cpu(tmp_path, monkeypatch, capsys):
+    """The offline cell at smoke size through ``trace_cell.py`` on the CPU:
+    one traced run of the harness with no check, the engine's programs
+    noted, their HLO read back, and the program's spans counted in the
+    window."""
     import jax
 
-    from chipbench.spec import resolve
+    import trace_cell
 
-    cell = resolve("mistral-7b.serve-offline-4k", smoke=True)
-    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    r = trace_cell.trace_cell(cell, 2**31 + 11, 1.0, jax.devices()[:1], peaks, str(tmp_path))
-    stats = r["spans"]["span_stats"]
+    devices = jax.devices()[:1]
+    info = {"platform": devices[0].platform, "kind": devices[0].device_kind, "count": 1}
+    monkeypatch.setattr(trace_cell, "resolve", lambda name: spec.resolve(name, smoke=True))
+    monkeypatch.setattr(harness, "on_chip", lambda cell: (devices, info, PEAKS))
+    cell = spec.resolve("mistral-7b.serve-offline-4k", smoke=True)
+    assert trace_cell.main(["--workload", cell.name, "--seed", str(2**31 + 11), "--seconds", "1",
+                            "--out", str(tmp_path)]) == 0
+    r = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert r["correct"] is None and r["checks"] == {}
+    values = json.loads((tmp_path / f"{cell.name}.values.json").read_text())
+    stats = values["spans"]["span_stats"]
     calls = stats["bench:serve_requests"]["count"]
     assert calls >= 1
     assert stats["nbi:serve.prefill"]["count"] == calls
     assert stats["nbi:serve.decode_step"]["count"] == calls * cell.traffic["gen_len"]
     names = sorted(p.name for p in tmp_path.iterdir())
-    assert names == sorted([f"{cell.name}.xplane.pb", f"{cell.name}.prefill.0.hlo.txt",
-                            f"{cell.name}.decode.0.hlo.txt"])
-    decode = sr.program_scopes((tmp_path / f"{cell.name}.decode.0.hlo.txt").read_text())
+    assert names == sorted(["trace.xplane.pb", f"{cell.name}.values.json",
+                            f"{cell.name}.0.prefill.hlo.txt", f"{cell.name}.1.decode.hlo.txt"])
+    decode = sr.program_scopes((tmp_path / f"{cell.name}.1.decode.hlo.txt").read_text())
     assert "kv_cache_write" in decode["scope"].values()
+    assert values["prefill_flops_per_call"] == cell.traffic["batch"] * flops.prefill_flops(
+        cell.config, cell.traffic["prompt_lengths"][0])

@@ -244,6 +244,24 @@ TINY_MOE = {"hidden_size": 8, "num_hidden_layers": 3, "num_attention_heads": 2,
                         "param_dtype": "bfloat16"}}
 
 
+def test_causal_kernel_work_by_hand():
+    # GQA, 4 query heads of 2 over 2 KV heads, bf16; 3 rows of 4 positions:
+    # 3 * 4 * (4 * 5 / 2) = 120 scores
+    work = flops.causal_kernel_work(TINY_GQA, 3, 4)
+    assert work["causal_flash_fwd"][0] == 2 * 120 * (2 + 2)
+    assert work["causal_flash_dq"][0] == 2 * 120 * (2 * 2 + 2)
+    assert work["causal_flash_dkv"][0] == 2 * 120 * (2 * 2 + 2 * 2)
+    # per position of 12: q and o 4*2*2 = 16 bytes, k and v 2*2*2 = 8, a
+    # float32 statistic 4*4 = 16
+    q, kv, stat = 12 * 16, 12 * 8, 12 * 16
+    assert work["causal_flash_fwd"][1] == q + 2 * kv + q + stat
+    assert work["causal_flash_dq"][1] == 3 * q + 2 * kv + 2 * stat
+    assert work["causal_flash_dkv"][1] == 2 * q + 4 * kv + 2 * stat
+    # MLA: a key and a value head per query head, of widths 4 and 1
+    mla = flops.causal_kernel_work(TINY_MLA, 1, 2)
+    assert mla["causal_flash_dkv"][0] == 2 * (2 * 3) * (2 * 4 + 2 * 1)
+
+
 def test_mla_without_query_compression_counts_by_hand():
     # wq 8*2*(2+2)=64 straight from the hidden state, wdkv 8*2, wukv 2*2*3,
     # wkr 8*2, wo 2*1*8; no wdq, no query norm
