@@ -91,7 +91,9 @@ class ServeEngine:
         self.rules = rules
         self.params = _init_params(self.model, self.mesh, rules, seed)
         self._prefill = jax.jit(make_prefill_step(self.model, rules, self.mesh))
-        self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh))
+        # the cache is donated: the step writes its new token in place
+        self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh),
+                               donate_argnums=(1,))
         self.stats = {"requests": 0, "prefill_tokens": 0, "decode_tokens": 0,
                       "prefill_s": 0.0, "decode_s": 0.0}
 
@@ -212,7 +214,8 @@ class ContinuousBatchingEngine:
         )
         self.params = _init_params(self.model, self.mesh, rules, seed)
         self._prefill = jax.jit(make_prefill_step(self.model, rules, self.mesh))
-        self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh))
+        self._decode = jax.jit(make_serve_step(self.model, rules, self.mesh),
+                               donate_argnums=(1,))
         self.stats = {"requests": 0, "decode_steps": 0, "slot_tokens": 0,
                       "occupancy_sum": 0.0}
 

@@ -141,6 +141,17 @@ def logical_to_spec(logical: tuple) -> "jax.sharding.PartitionSpec":
     return P(*axes)
 
 
+def axis_devices(name: str) -> int:
+    """How many devices the active rules and mesh split the logical axis
+    ``name`` over: 1 without rules, or where the rule maps it to no axis."""
+    if _ACTIVE_RULES is None or _ACTIVE_MESH is None:
+        return 1
+    axes = _ACTIVE_RULES.get(name)
+    if axes is None:
+        return 1
+    return math.prod(_ACTIVE_MESH.shape[a] for a in ((axes,) if isinstance(axes, str) else axes))
+
+
 def shard(x, *logical):
     """with_sharding_constraint by logical axis names (no-op without rules)."""
     if _ACTIVE_RULES is None or _ACTIVE_MESH is None:
@@ -406,29 +417,40 @@ def fused_causal_attention(q, k, v, kv_heads: str = "kv_heads"):
     )(q, k, v)
 
 
-def attention_single_shot(q, k, v, *, mask=None, logit_cap: float = 0.0):
+def attention_single_shot(q, k, v, *, mask=None, logit_cap: float = 0.0, new_kv=None):
     """Naive attention for tiny Sq (decode): one (B,H,Sq,Skv) score tensor.
 
     With the KV sequence dim sharded over the ``model`` mesh axis this is
     exactly flash-decoding's split-KV: GSPMD turns the softmax reductions
     into tiny per-shard partials + an all-reduce.
+
+    ``new_kv`` = (k_new, v_new), each (B, Hkv, T, Dh), are keys and values
+    that every query sees besides ``k`` and ``v`` (decode: the new token,
+    not yet in the cache). They join the one softmax by a shared max and
+    sum, in score space, so the cache is never copied to append them.
     """
     B, Hq, Sq, Dh = q.shape
     _, Hkv, Skv, _ = k.shape
     G = Hq // Hkv
     qg = q.reshape(B, Hkv, G, Sq, Dh) * (Dh**-0.5)
-    s = jnp.einsum("bhgqd,bhsd->bhgqs", qg, k, preferred_element_type=jnp.float32)
-    if logit_cap > 0:
-        s = logit_cap * jnp.tanh(s / logit_cap)
+
+    def scores(keys):
+        s = jnp.einsum("bhgqd,bhsd->bhgqs", qg, keys, preferred_element_type=jnp.float32)
+        return logit_cap * jnp.tanh(s / logit_cap) if logit_cap > 0 else s
+
+    s = scores(k)
     if mask is not None:
         s = jnp.where(mask, s, NEG_INF)
-    m = s.max(axis=-1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = p.sum(axis=-1, keepdims=True)
-    out = jnp.einsum(
-        "bhgqs,bhsd->bhgqd", (p / jnp.maximum(l, 1e-30)).astype(v.dtype), v,
-        preferred_element_type=jnp.float32,
-    )
+    parts = [(s, v)] if new_kv is None else [(s, v), (scores(new_kv[0]), new_kv[1])]
+    m = functools.reduce(jnp.maximum, [x.max(axis=-1, keepdims=True) for x, _ in parts])
+    parts = [(jnp.exp(x - m), vals) for x, vals in parts]
+    l = functools.reduce(jnp.add, [p.sum(axis=-1, keepdims=True) for p, _ in parts])
+    l = jnp.maximum(l, 1e-30)
+    out = functools.reduce(jnp.add, [
+        jnp.einsum("bhgqs,bhsd->bhgqd", (p / l).astype(vals.dtype), vals,
+                   preferred_element_type=jnp.float32)
+        for p, vals in parts
+    ])
     return out.reshape(B, Hq, Sq, Dh).astype(q.dtype)
 
 

@@ -30,6 +30,7 @@ from .transformer import (
     mlp_defs,
     remat_wrap,
     unembed,
+    write_cache,
 )
 
 # ---------------------------------------------------------------------------
@@ -308,6 +309,8 @@ def moe_cache_logical(cfg: ArchConfig) -> dict:
 
 
 def moe_decode_step(params, cfg: ArchConfig, cache, tokens, pos):
+    """One decode step; each layer scan yields its layers' new tokens, and
+    one write after each scan puts them into the cache (:func:`write_cache`)."""
     h = embed_tokens(params, cfg, tokens)
     dt = jnp.dtype(cfg.dtype)
 
@@ -316,25 +319,23 @@ def moe_decode_step(params, cfg: ArchConfig, cache, tokens, pos):
 
         def dense_body(h, inp):
             p, c = inp
-            y, nc = gqa_decode_attn(p["attn"], c, rms_norm(h, p["ln1"]), cfg, pos)
+            y, new = gqa_decode_attn(p["attn"], c, rms_norm(h, p["ln1"]), cfg, pos)
             h = h + y
             m = p["mlp"]
             h = h + swiglu(rms_norm(h, p["ln2"]), m["wg"], m["wi"], m["wo"], dt)
-            return h, nc
+            return h, new
 
-        h, new_cache["dense"] = jax.lax.scan(
-            dense_body, h, (params["dense_blocks"], cache["dense"])
-        )
+        h, new = jax.lax.scan(dense_body, h, (params["dense_blocks"], cache["dense"]))
+        new_cache["dense"] = write_cache(cache["dense"], new, pos)
 
     def moe_body(h, inp):
         p, c = inp
-        y, nc = gqa_decode_attn(p["attn"], c, rms_norm(h, p["ln1"]), cfg, pos)
+        y, new = gqa_decode_attn(p["attn"], c, rms_norm(h, p["ln1"]), cfg, pos)
         h = h + y
         h = h + moe_decode_ffn(p["moe"], rms_norm(h, p["ln2"]), cfg)
-        return h, nc
+        return h, new
 
-    h, new_cache["moe"] = jax.lax.scan(
-        moe_body, h, (params["moe_blocks"], cache["moe"])
-    )
+    h, new = jax.lax.scan(moe_body, h, (params["moe_blocks"], cache["moe"]))
+    new_cache["moe"] = write_cache(cache["moe"], new, pos)
     h = rms_norm(h, params["final_ln"])
     return unembed(params, cfg, h), new_cache

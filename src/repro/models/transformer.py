@@ -17,6 +17,8 @@ Layout conventions
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -26,6 +28,7 @@ from .common import (
     attention_chunked,
     attention_path,
     attention_single_shot,
+    axis_devices,
     causal_mask,
     cross_entropy,
     fused_causal_attention,
@@ -356,7 +359,8 @@ def scatter_seq(buf, update, pos):
     """Write `update` (..., 1, d) into `buf` (..., S, d) at index `pos`.
 
     One-hot multiply-add instead of dynamic_update_slice: elementwise →
-    GSPMD-shardable when S is sharded over the `model` axis.
+    GSPMD-shardable when S is sharded over the `model` axis. It reads and
+    writes all of ``buf``, so :func:`write_cache` takes it only there.
 
     ``pos`` may be a scalar (whole batch at one position) or a (B,) vector
     (continuous batching: every slot at its own depth); vector positions
@@ -373,6 +377,40 @@ def scatter_seq(buf, update, pos):
     return buf * (1 - onehot) + update.astype(buf.dtype) * onehot
 
 
+@scoped("kv_cache_write")
+def _write_seq(buf, update, pos):
+    """``update`` (L, B, ..., 1, d) into ``buf`` (L, B, ..., S, d) at sequence
+    position ``pos``, in place where ``buf`` is donated: one
+    dynamic_update_slice for a scalar ``pos``, one a row for a (B,) ``pos``."""
+    update = update.astype(buf.dtype)
+    pos = jnp.asarray(pos)
+    seq = buf.ndim - 2
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice_in_dim(buf, update, pos, axis=seq)
+    # one slice update a row: XLA on the TPU gives a vmapped update or a
+    # scatter its own layout of the cache, and copies the whole cache twice
+    for b in range(pos.shape[0]):
+        start = [0] * buf.ndim
+        start[1], start[seq] = b, pos[b]
+        buf = jax.lax.dynamic_update_slice(buf, update[:, b:b + 1], start)
+    return buf
+
+
+def write_cache(cache, new, pos):
+    """The decode step's one cache write, after the layer scan: each leaf of
+    ``new`` holds every layer's new token (L, B, ..., 1, d) and goes into the
+    stacked ``cache`` (L, B, ..., S, d) at ``pos`` (scalar or (B,)).
+
+    Where the rules split ``kv_seq`` over more than one device, the one-hot
+    blend (:func:`scatter_seq`, layer by layer), which GSPMD partitions
+    elementwise."""
+    if axis_devices("kv_seq") > 1:
+        write = jax.vmap(lambda b, u: scatter_seq(b, u, pos))
+    else:
+        write = functools.partial(_write_seq, pos=pos)
+    return jax.tree_util.tree_map(write, cache, new)
+
+
 def _pos_rope(pos, batch: int):
     """Positions for RoPE at decode: scalar → (1,); vector → (B,1,1) so the
     angle tensor broadcasts against (B, H, 1, dh/2)."""
@@ -382,16 +420,21 @@ def _pos_rope(pos, batch: int):
     return jnp.broadcast_to(pos, (batch,))[:, None, None]
 
 
-def _pos_mask(pos, batch: int, skv: int):
-    """(B,1,1,1,S) causal mask rows for scalar or per-row positions."""
-    pos = jnp.asarray(pos)
-    pos_b = jnp.broadcast_to(pos, (batch,)) if pos.ndim else jnp.full((batch,), pos)
-    return jnp.arange(skv)[None, None, None, None, :] <= pos_b[:, None, None, None, None]
+def _seen_mask(pos, batch: int, skv: int, window: int = 0):
+    """(B,1,1,1,S) mask of the cache positions a decode query at ``pos``
+    (scalar or (B,)) attends besides its own token: those before ``pos``
+    and, with a local ``window``, after ``pos - window``."""
+    pos = jnp.broadcast_to(jnp.asarray(pos), (batch,))[:, None, None, None, None]
+    k_pos = jnp.arange(skv)[None, None, None, None, :]
+    mask = k_pos < pos
+    return mask & (k_pos > pos - window) if window > 0 else mask
 
 
 @scoped("decode_attention")
 def gqa_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
-    """One-token attention against the cache; ``pos`` scalar or (B,)."""
+    """One-token attention against the cache and the new token; ``pos``
+    scalar or (B,). The cache is read, not written: returns (y, the new
+    token's {"k", "v"} (B, Hkv, 1, hd) in the cache dtype)."""
     dt = jnp.dtype(cfg.dtype)
     B = x.shape[0]
     q = jnp.einsum("bsd,dhk->bhsk", x, p["wq"].astype(dt))
@@ -399,26 +442,25 @@ def gqa_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
     v_new = jnp.einsum("bsd,dhk->bhsk", x, p["wv"].astype(dt))
     positions = _pos_rope(pos, B)
     q = apply_rope(q, positions, cfg.rope_theta)
-    k_new = apply_rope(k_new, positions, cfg.rope_theta)
-    k = scatter_seq(layer_cache["k"], k_new, pos)
-    v = scatter_seq(layer_cache["v"], v_new, pos)
-    k = shard(k, "batch", None, "kv_seq", None)
-    v = shard(v, "batch", None, "kv_seq", None)
-    S = k.shape[-2]
-    mask = _pos_mask(pos, B, S)
-    if cfg.attention == "local" and cfg.window > 0:
-        low = _pos_mask(jnp.asarray(pos) - cfg.window, B, S)
-        mask &= ~low  # k_pos > pos - window
-    out = attention_single_shot(q, k, v, mask=mask, logit_cap=cfg.logit_cap)
+    k_new = apply_rope(k_new, positions, cfg.rope_theta).astype(layer_cache["k"].dtype)
+    v_new = v_new.astype(layer_cache["v"].dtype)
+    k = shard(layer_cache["k"], "batch", None, "kv_seq", None)
+    v = shard(layer_cache["v"], "batch", None, "kv_seq", None)
+    window = cfg.window if cfg.attention == "local" else 0
+    mask = _seen_mask(pos, B, k.shape[-2], window)
+    out = attention_single_shot(q, k, v, mask=mask, logit_cap=cfg.logit_cap,
+                                new_kv=(k_new, v_new))
     y = jnp.einsum("bhsk,hkd->bsd", out, p["wo"].astype(dt))
-    return y, {"k": k, "v": v}
+    return y, {"k": k_new, "v": v_new}
 
 
 @scoped("decode_attention")
 def mla_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
-    """Absorbed-matmul MLA decode over the compressed (ckv, k_rope) cache.
+    """Absorbed-matmul MLA decode over the compressed (ckv, k_rope) cache and
+    the new token, which joins the softmax in score space.
 
-    ``pos`` scalar or (B,) (continuous batching)."""
+    ``pos`` scalar or (B,) (continuous batching). Returns (y, the new token's
+    {"ckv": (B, 1, r), "krope": (B, 1, rope)} in the cache dtype)."""
     dt = jnp.dtype(cfg.dtype)
     B = x.shape[0]
     nope, rope_d = cfg.qk_nope_dim, cfg.qk_rope_dim
@@ -431,39 +473,49 @@ def mla_decode_attn(p, layer_cache, x, cfg: ArchConfig, pos):
         jnp.einsum("bsd,dk->bsk", x, p["wkr"].astype(dt))[:, None], positions,
         cfg.rope_theta,
     )[:, 0]
-    ckv = scatter_seq(layer_cache["ckv"], ckv_new, pos)
-    krope = scatter_seq(layer_cache["krope"], krope_new, pos)
-    ckv = shard(ckv, "batch", "kv_seq", None)
-    krope = shard(krope, "batch", "kv_seq", None)
+    ckv_new = ckv_new.astype(layer_cache["ckv"].dtype)
+    krope_new = krope_new.astype(layer_cache["krope"].dtype)
+    ckv = shard(layer_cache["ckv"], "batch", "kv_seq", None)
+    krope = shard(layer_cache["krope"], "batch", "kv_seq", None)
     wuk = p["wukv"][..., :nope].astype(dt)  # (r, H, nope)
     wuv = p["wukv"][..., nope:].astype(dt)  # (r, H, v)
     q_abs = jnp.einsum("bhsk,rhk->bhsr", q_nope, wuk)
-    s = jnp.einsum("bhsr,btr->bhst", q_abs, ckv) + jnp.einsum(
-        "bhsk,btk->bhst", q_rope, krope
-    )
-    s = s.astype(jnp.float32) * ((nope + rope_d) ** -0.5)
-    S = ckv.shape[1]
-    s = jnp.where(_pos_mask(pos, B, S)[:, :, 0], s, -1e30)  # (B,1,1,S)
-    w = jax.nn.softmax(s, axis=-1).astype(dt)
-    ctx = jnp.einsum("bhst,btr->bhsr", w, ckv)
+
+    def scores(c, kr):
+        s = jnp.einsum("bhsr,btr->bhst", q_abs, c) + jnp.einsum("bhsk,btk->bhst", q_rope, kr)
+        return s.astype(jnp.float32) * ((nope + rope_d) ** -0.5)
+
+    s = jnp.where(_seen_mask(pos, B, ckv.shape[1])[:, :, 0], scores(ckv, krope), -1e30)
+    s_new = scores(ckv_new, krope_new)  # (B,H,1,1)
+    m = jnp.maximum(s.max(axis=-1, keepdims=True), s_new)
+    w, w_new = jnp.exp(s - m), jnp.exp(s_new - m)
+    l = w.sum(axis=-1, keepdims=True) + w_new
+    ctx = (jnp.einsum("bhst,btr->bhsr", (w / l).astype(dt), ckv,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bhst,btr->bhsr", (w_new / l).astype(dt), ckv_new,
+                        preferred_element_type=jnp.float32)).astype(dt)
     out_h = jnp.einsum("bhsr,rhv->bhsv", ctx, wuv)
     y = jnp.einsum("bhsv,hvd->bsd", out_h, p["wo"].astype(dt))
-    return y, {"ckv": ckv, "krope": krope}
+    return y, {"ckv": ckv_new, "krope": krope_new}
 
 
 def dense_decode_step(params, cfg: ArchConfig, cache, tokens, pos):
-    """One decode step. tokens: (B, 1) int32; pos: scalar int32."""
+    """One decode step. tokens: (B, 1) int32; pos: scalar or (B,) int32.
+
+    The layer scan reads the cache and yields each layer's new token; one
+    write (:func:`write_cache`) then puts them all at ``pos``. Returns
+    (logits, cache)."""
     h = embed_tokens(params, cfg, tokens)
     decode_attn = mla_decode_attn if cfg.attention == "mla" else gqa_decode_attn
 
     def body(h, inp):
         layer_p, layer_c = inp
-        y, new_c = decode_attn(layer_p["attn"], layer_c, rms_norm(h, layer_p["ln1"]), cfg, pos)
+        y, new = decode_attn(layer_p["attn"], layer_c, rms_norm(h, layer_p["ln1"]), cfg, pos)
         h = h + y
         m = layer_p["mlp"]
         h = h + swiglu(rms_norm(h, layer_p["ln2"]), m["wg"], m["wi"], m["wo"], jnp.dtype(cfg.dtype))
-        return h, new_c
+        return h, new
 
-    h, new_cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    h, new = jax.lax.scan(body, h, (params["blocks"], cache))
     h = rms_norm(h, params["final_ln"])
-    return unembed(params, cfg, h), new_cache
+    return unembed(params, cfg, h), write_cache(cache, new, pos)

@@ -198,3 +198,94 @@ class TestVectorPos:
             np.testing.assert_allclose(
                 np.asarray(lm[b]), np.asarray(lb[0]), atol=2e-5
             )
+
+
+class TestCacheDonation:
+    """Both engines donate the cache to the decode step, which writes the
+    new token in place: the step's input cache is consumed, and the compiled
+    program aliases every cache leaf to its output."""
+
+    @pytest.mark.parametrize("kind", ["static", "continuous"])
+    def test_decode_consumes_and_aliases_the_cache(self, kind):
+        import re
+
+        from repro.launch.serve import ContinuousBatchingEngine
+
+        cfg = get_smoke_config("codeqwen1.5-7b")
+        cls = ServeEngine if kind == "static" else ContinuousBatchingEngine
+        eng = cls(cfg, batch=2, max_seq=32, seed=0)
+        cache = jax.tree_util.tree_map(lambda sd: jnp.zeros(sd.shape, sd.dtype),
+                                       eng.model.cache_defs_fn(2, 32))
+        pos = jnp.asarray(5 if kind == "static" else [5, 9], jnp.int32)
+        args = (eng.params, cache, jnp.zeros((2, 1), jnp.int32), pos)
+        with eng.mesh:
+            text = eng._decode.lower(*args).compile().as_text()
+            eng._decode(*args)
+        leaves = jax.tree_util.tree_leaves(cache)
+        assert all(leaf.is_deleted() for leaf in leaves)
+        first = len(jax.tree_util.tree_leaves(eng.params))
+        aliased = {int(i) for i in re.findall(r"\((\d+), \{\}, (?:may|must)-alias\)", text)}
+        assert aliased == set(range(first, first + len(leaves)))
+
+
+_SEQ_SHARDED_CHILD = """
+import json, os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from repro.configs import get_smoke_config
+from repro.launch.mesh import make_host_mesh
+from repro.launch.serve import ContinuousBatchingEngine, ServeEngine
+from repro.models import transformer as tx
+
+blends = []
+scatter_seq = tx.scatter_seq
+tx.scatter_seq = lambda *a, **k: blends.append(1) or scatter_seq(*a, **k)
+rng = np.random.default_rng(5)
+out = {}
+for arch in ("codeqwen1.5-7b", "minicpm3-4b"):
+    cfg = get_smoke_config(arch)
+    prompts = rng.integers(0, cfg.vocab_size, (2, 12)).astype(np.int32)
+    reqs = [rng.integers(0, cfg.vocab_size, size=n).astype(np.int32) for n in (12, 5, 9)]
+    for name, mesh in (("one", make_host_mesh(devices=jax.devices()[:1])),
+                       ("four", make_host_mesh((1, 4)))):
+        del blends[:]
+        eng = ServeEngine(cfg, batch=2, max_seq=48, mesh=mesh, seed=0)
+        cb = ContinuousBatchingEngine(cfg, batch=2, max_seq=48, mesh=mesh, seed=0)
+        out[f"{arch}/{name}"] = {
+            "kv_seq": eng.rules["kv_seq"],
+            "static": eng.generate_batch(prompts, 6).tolist(),
+            "continuous": [o.tolist() for o in cb.serve(reqs, 4)],
+            "blends": len(blends),
+        }
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def seq_sharded():
+    """Both engines on four virtual CPU devices with ``kv_seq`` split over
+    ``model`` (a 1x4 mesh), and on one device, in a child process."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(src))
+    res = subprocess.run([sys.executable, "-c", _SEQ_SHARDED_CHILD], env=env,
+                         capture_output=True, text=True, timeout=280)
+    assert res.returncode == 0, res.stderr[-2000:]
+    return json.loads(res.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("arch", ["codeqwen1.5-7b", "minicpm3-4b"])
+def test_sharded_kv_seq_generates_as_one_device(seq_sharded, arch):
+    """With ``kv_seq`` split over four devices the decode step writes the
+    cache by the one-hot blend, and both engines give the tokens they give
+    on one device, where the blend is never taken."""
+    one, four = seq_sharded[f"{arch}/one"], seq_sharded[f"{arch}/four"]
+    assert (one["kv_seq"], four["kv_seq"]) == (None, "model")
+    assert one["blends"] == 0 and four["blends"] > 0
+    assert four["static"] == one["static"]
+    assert four["continuous"] == one["continuous"]
