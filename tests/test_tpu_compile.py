@@ -133,7 +133,9 @@ def test_decode_step_updates_a_donated_cache_in_place(spec, arch, pos_shape):
     the output is the cache's own buffer, no temporary holds a copy of it,
     and the step reads the cache once and writes only the new token (bytes
     accessed below the weights plus 1.5 caches; a step that rewrites the
-    cache moves three)."""
+    cache moves three). Every instruction that yields a whole cache leaf is
+    a write under ``kv_cache_write``, unfused with other work, which is what
+    ``decode_cache_write_ms`` reads."""
     model = build_model(get_config(arch).replace(n_layers=2, n_patches=0))
     shapes = lambda tree: jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), tree)  # noqa: E731
     params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
@@ -146,3 +148,8 @@ def test_decode_step_updates_a_donated_cache_in_place(spec, arch, pos_shape):
     assert memory.alias_size_in_bytes == nbytes(cache)
     assert memory.temp_size_in_bytes < 0.1 * nbytes(cache)
     assert cost["bytes accessed"] < nbytes(params) + 1.5 * nbytes(cache)
+    hlo = compiled.as_text()
+    for leaf in jax.tree_util.tree_leaves(cache):
+        shape = re.escape(f"bf16[{','.join(map(str, leaf.shape))}]")
+        writes = re.findall(rf"= {shape}\{{[^}}]*\}} (?:fusion|dynamic-update-slice)\(.*", hlo)
+        assert writes and all("/kv_cache_write/" in w for w in writes), leaf.shape
